@@ -47,6 +47,9 @@ def run_bench(name: str) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     names = sys.argv[1:] or list(BENCHES)
     unknown = [n for n in names if n not in BENCHES]
     if unknown:

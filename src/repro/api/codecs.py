@@ -82,6 +82,12 @@ FLAG_DELTA = 1 << 0
 # magic, version, flags, round, manifest len, body len, crc, base digest
 _HEADER = struct.Struct("<4sHHIIQII")
 _PVT_BYTES_PER_ENTRY = 8  # s and b, f32 each
+# Fields per device call of the wire pack/unpack.  A multiple of 32, so each
+# chunk ends on a word boundary and the chunks' words concatenate into the
+# canonical stream.  It bounds what one call holds on the device whatever
+# the size of the leaf, and the TPU compile time of each call's shape
+# (which grows with the call's size: 2 s at 2^18 fields, 45 s at 2^22).
+_CHUNK_FIELDS = 1 << 18
 
 
 class CodecError(ValueError):
@@ -188,28 +194,32 @@ def negotiate_version(peer_versions: Sequence[int]) -> int:
 
 def _flatten(tree) -> List[Tuple[List[Any], Any]]:
     out: List[Tuple[List[Any], Any]] = []
-
-    def walk(node, prefix):
-        if is_compressed(node):
-            out.append((prefix, node))
-        elif isinstance(node, dict):
-            if not node:
-                raise CodecError("empty dict container is not serializable")
-            for k in sorted(node):  # jax tree order: sorted dict keys
-                if not isinstance(k, str):
-                    raise CodecError(f"non-string dict key {k!r} in wire tree")
-                walk(node[k], prefix + [["k", k]])
-        elif isinstance(node, (list, tuple)):
-            if not node:
-                raise CodecError("empty sequence container is not serializable")
-            tag = "i" if isinstance(node, list) else "t"
-            for j, v in enumerate(node):
-                walk(v, prefix + [[tag, j]])
-        else:
-            out.append((prefix, node))
-
-    walk(tree, [])
+    _walk(tree, [], out)
     return out
+
+
+def _walk(node, prefix, out) -> None:
+    # A module-level function, not a closure: a self-referencing nested
+    # ``walk`` would hold ``out`` (and so every device leaf) in a reference
+    # cycle until the garbage collector ran, keeping a whole model's codes
+    # on the device after encode, digest or hot swap.
+    if is_compressed(node):
+        out.append((prefix, node))
+    elif isinstance(node, dict):
+        if not node:
+            raise CodecError("empty dict container is not serializable")
+        for k in sorted(node):  # jax tree order: sorted dict keys
+            if not isinstance(k, str):
+                raise CodecError(f"non-string dict key {k!r} in wire tree")
+            _walk(node[k], prefix + [["k", k]], out)
+    elif isinstance(node, (list, tuple)):
+        if not node:
+            raise CodecError("empty sequence container is not serializable")
+        tag = "i" if isinstance(node, list) else "t"
+        for j, v in enumerate(node):
+            _walk(v, prefix + [[tag, j]], out)
+    else:
+        out.append((prefix, node))
 
 
 class _Node:
@@ -303,7 +313,25 @@ def _codes_np(cv: CompressedVariable) -> np.ndarray:
 
 
 def _pack_np(codes_flat: np.ndarray, bits: int) -> np.ndarray:
-    return np.asarray(packing.pack(codes_flat, bits), np.uint32)
+    n = codes_flat.size
+    if n <= _CHUNK_FIELDS:
+        return np.asarray(packing.pack(codes_flat, bits), np.uint32)
+    return np.concatenate([
+        np.asarray(packing.pack(codes_flat[i:i + _CHUNK_FIELDS], bits),
+                   np.uint32)
+        for i in range(0, n, _CHUNK_FIELDS)
+    ])
+
+
+def _unpack_np(words: np.ndarray, bits: int, n: int) -> np.ndarray:
+    if n <= _CHUNK_FIELDS:
+        return np.asarray(packing.unpack(words, bits, n), np.uint32)
+    step = _CHUNK_FIELDS * bits // 32  # words per full chunk
+    return np.concatenate([
+        np.asarray(packing.unpack(words[k * step:(k + 1) * step], bits,
+                                  min(_CHUNK_FIELDS, n - i)), np.uint32)
+        for k, i in enumerate(range(0, n, _CHUNK_FIELDS))
+    ])
 
 
 def _encode_omc(cv: CompressedVariable, base) -> Tuple[Dict[str, Any], List[bytes]]:
@@ -401,13 +429,13 @@ def _decode_omc(meta: Dict[str, Any], body: memoryview, off: int, base):
             nwords = packing.packed_words(nnz, fmt.bits)
             words = np.frombuffer(body, np.uint32, nwords, off)
             off += 4 * nwords
-            xor = np.asarray(packing.unpack(words, fmt.bits, nnz), np.uint32)
+            xor = _unpack_np(words, fmt.bits, nnz)
             codes[idx] ^= xor
     else:
         nwords = packing.packed_words(n, fmt.bits)
         words = np.frombuffer(body, np.uint32, nwords, off)
         off += 4 * nwords
-        codes = np.asarray(packing.unpack(words, fmt.bits, n), np.uint32)
+        codes = _unpack_np(words, fmt.bits, n)
     cv = CompressedVariable(
         jnp.asarray(codes.reshape(shape).astype(np.dtype(fmt.container_dtype))),
         jnp.asarray(s.reshape(sb_shape), jnp.float32),

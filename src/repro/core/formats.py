@@ -21,10 +21,11 @@ Semantics notes (see DESIGN.md §2):
     2**(1 - bias - M).
   * ``jax.lax.reduce_precision(x, E, M)`` is the oracle for RNE on *normal*
     values, but it flushes target subnormals to zero and overflows to inf.
-    ``value_quantize`` uses it for the normal range, a scaled
-    round-half-even for the subnormal range, and clamps to ±max_normal
-    (OMC storage must never hold inf).  For (5, 10) this reproduces the
-    float16 cast bit-for-bit, subnormals included (tested).
+    ``value_quantize`` rounds the normal range on the raw bits (equal to
+    ``reduce_precision`` there, and lowerable inside Pallas TPU kernels),
+    uses a scaled round-half-even for the subnormal range, and clamps to
+    ±max_normal (OMC storage must never hold inf).  For (5, 10) this
+    reproduces the float16 cast bit-for-bit, subnormals included (tested).
   * The bitfield layout is IEEE-like: exponent bias ``2**(E-1)-1``, top
     exponent field reserved for inf/NaN (NaN is propagated so that a poisoned
     training state stays visible; inf is saturated away).
@@ -116,41 +117,48 @@ class FloatFormat:
 FP32 = FloatFormat(8, 23)
 
 
-def _value_quantize_e8(x: jax.Array, fmt: FloatFormat) -> jax.Array:
-    """Integer-bit RNE for exp_bits == 8 formats (bf16-family, incl. FP32).
+def _round_mantissa(x: jax.Array, mant_bits: int) -> jax.Array:
+    """Round-to-nearest-even of f32 ``x`` to ``mant_bits`` mantissa bits.
 
-    E8 formats share float32's exponent range, so their subnormals ARE f32
-    subnormals — XLA CPU flushes those in float arithmetic (FTZ/DAZ), which
-    breaks the float-path quantizer.  The classic add-half-and-truncate trick
-    on the raw bits handles normals and subnormals uniformly and exactly.
+    The add-half-and-truncate trick on the raw bits: exact for f32 normals
+    and f32 subnormals alike (XLA CPU flushes subnormals in float
+    arithmetic, so E8 formats, whose subnormals ARE f32 subnormals, need
+    it), and built from integer ops that both XLA and the Pallas TPU
+    lowering accept (Mosaic has no ``reduce_precision``).  The caller
+    clamps first: a magnitude ``<= max_normal`` never carries past it,
+    because max_normal has zero low bits.
     """
-    sh = 23 - fmt.mant_bits
-    xc = jnp.clip(x, -fmt.max_normal, fmt.max_normal)  # NaN propagates
-    b = jax.lax.bitcast_convert_type(xc, jnp.uint32)
+    sh = 23 - mant_bits
+    if sh == 0:
+        return x
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
     lsb = (b >> sh) & np.uint32(1)
-    rb = b + (np.uint32((1 << (sh - 1)) - 1) + lsb) if sh > 0 else b
+    rb = b + (np.uint32((1 << (sh - 1)) - 1) + lsb)
     rb = rb & np.uint32(~((1 << sh) - 1) & 0xFFFFFFFF)
-    out = jax.lax.bitcast_convert_type(rb, jnp.float32)
-    # The carry can only round magnitudes upward within the clipped range
-    # (max_normal has zero low bits), so no overflow to inf is possible.
-    return jnp.where(jnp.isnan(x), x, out)
+    return jax.lax.bitcast_convert_type(rb, jnp.float32)
 
 
 def value_quantize(x: jax.Array, fmt: FloatFormat) -> jax.Array:
-    """Nearest representable value: RNE, subnormal-aware, saturating. f32->f32."""
+    """Nearest representable value: RNE, subnormal-aware, saturating. f32->f32.
+
+    One formulation for XLA and the Pallas kernels (``kernels/agg.py``,
+    ``kernels/quantize.py``): equal bit for bit to
+    ``reduce_precision(x, E, M)`` on the format's normal range.
+    """
     x = jnp.asarray(x, jnp.float32)
     if fmt.is_identity:
         return x
-    if fmt.exp_bits == 8:
-        return _value_quantize_e8(x, fmt)
     xc = jnp.clip(x, -fmt.max_normal, fmt.max_normal)  # NaN propagates
-    normal = jax.lax.reduce_precision(xc, fmt.exp_bits, fmt.mant_bits)
-    # Subnormal range: |x| < min_normal rounds (half-to-even) to a multiple of
-    # the subnormal step.  For exp_bits <= 7 the step is a normal f32
-    # (>= 2**-85), so the division/round/multiply chain is exact.
-    step = np.float32(fmt.subnormal_step)
-    sub = jnp.round(xc / step) * step
-    return jnp.where(jnp.abs(xc) < fmt.min_normal, sub, normal)
+    out = _round_mantissa(xc, fmt.mant_bits)
+    if fmt.exp_bits < 8:
+        # Subnormal range: |x| < min_normal rounds (half-to-even) to a
+        # multiple of the subnormal step.  For exp_bits <= 7 the step is a
+        # normal f32 (>= 2**-85), so the division/round/multiply chain is
+        # exact.
+        step = np.float32(fmt.subnormal_step)
+        sub = jnp.round(xc / step) * step
+        out = jnp.where(jnp.abs(xc) < fmt.min_normal, sub, out)
+    return jnp.where(jnp.isnan(x), x, out)
 
 
 def encode(x: jax.Array, fmt: FloatFormat, *, quantize: bool = True) -> jax.Array:
@@ -186,8 +194,11 @@ def encode(x: jax.Array, fmt: FloatFormat, *, quantize: bool = True) -> jax.Arra
     #     CPU; the field is m32 >> (150 - bias - mant_bits) exactly (low bits
     #     are zero for representable inputs).
     absx = jax.lax.bitcast_convert_type(mag, jnp.float32)
-    m_sub = jnp.round(absx / np.float32(fmt.subnormal_step)).astype(jnp.uint32)
-    m_sub = jnp.minimum(m_sub, np.uint32((1 << z) - 1))
+    # Clamped before the cast, which goes through int32: Mosaic has no
+    # float -> uint32 conversion.
+    m_sub = jnp.round(jnp.minimum(absx / np.float32(fmt.subnormal_step),
+                                  np.float32((1 << z) - 1)))
+    m_sub = m_sub.astype(jnp.int32).astype(jnp.uint32)
     sub_shift = 150 - fmt.bias - z  # >= 0 for every supported format
     m_sub_tiny = (mag >> min(sub_shift, 31)) if sub_shift < 32 else jnp.zeros_like(mag)
     m_sub = jnp.where(e32 == 0, m_sub_tiny, m_sub)
@@ -223,8 +234,10 @@ def decode(code: jax.Array, fmt: FloatFormat) -> jax.Array:
         sub = jax.lax.bitcast_convert_type(sign31 | (m << (23 - z)), jnp.float32)
     else:
         # exp_bits <= 7: the step 2**(1-bias-z) >= 2**-85 is a normal f32, so
-        # integer-times-power-of-two is exact.
-        sub = m.astype(jnp.float32) * np.float32(2.0 ** (1 - fmt.bias - z))
+        # integer-times-power-of-two is exact.  m < 2**23, so the cast goes
+        # through int32 (Mosaic has no uint32 -> float conversion).
+        sub = m.astype(jnp.int32).astype(jnp.float32) * np.float32(
+            2.0 ** (1 - fmt.bias - z))
         sub = jnp.where(sign == 1, -sub, sub)
     # Specials.
     inf_bits = sign31 | np.uint32(0x7F800000)

@@ -48,39 +48,43 @@ from repro.core.formats import (
     value_quantize as _jnp_value_quantize,
 )
 
-TILE = 1024  # lane-dim tile (multiple of 128), matches kernels/quantize.py
+LANES = 128  # lane width of one vreg
+TILE_ROWS = 32  # sublane rows per block: a multiple of every container's
+                # native tiling (u32: 8, u16: 16, u8: 32 rows)
+TILE = TILE_ROWS * LANES  # elements per block
 
 
 def _fused_kernel(srv_ref, ss_ref, sb_ref, cl_ref, cs_ref, cb_ref, w_ref,
-                  lr_ref, o_ref, sums_ref, *, fmt: FloatFormat, m: int):
+                  k_ref, o_ref, sums_ref, *, fmt: FloatFormat, m: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         sums_ref[...] = jnp.zeros_like(sums_ref)
 
-    w = w_ref[...]  # (C, 1)
-    wsum = jnp.maximum(jnp.sum(w), 1e-9)
-    old = _jnp_decode(srv_ref[...], fmt) * ss_ref[0, 0] + sb_ref[0, 0]  # (1, T)
-    x = _jnp_decode(cl_ref[...][:, 0, :], fmt)  # (C, T)
+    w = w_ref[...]  # (C, 1, 1)
+    lr, wsum = k_ref[0, 0], k_ref[0, 1]
+    old = _jnp_decode(srv_ref[...], fmt) * ss_ref[...] + sb_ref[...]  # (R, L)
+    x = _jnp_decode(cl_ref[...], fmt)  # (C, R, L)
     x = x * cs_ref[...] + cb_ref[...]
     # Zero dead rows BEFORE the mean — mirrors engine's where(alive, x, 0);
     # where (not multiply) so NaN in failed-client rows cannot propagate.
     x = jnp.where(w > 0, x, 0.0)
-    acc = jnp.sum(x * w, axis=0, keepdims=True) / wsum
-    new = old + lr_ref[0, 0] * (acc - old)
+    acc = jnp.sum(x * w, axis=0) / wsum
+    new = old + lr * (acc - old)
     vq = _jnp_value_quantize(new, fmt)
     o_ref[...] = _jnp_encode(vq, fmt, quantize=False)
     # PVT sums over true elements only: the padded tail decodes to the
-    # padded-code value, not 0, and would bias the affine solve.
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, new.shape[1]), 1)
-    valid = col + j * new.shape[1] < m
+    # padded-code value, not 0, and would bias the affine solve.  Partial
+    # sums stay per lane; the lanes are summed outside the kernel.
+    row = jax.lax.broadcasted_iota(jnp.int32, new.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, new.shape, 1)
+    valid = (j * TILE_ROWS + row) * LANES + col < m
     nv = jnp.where(valid, new, 0.0)
     qv = jnp.where(valid, vq, 0.0)
-    sums_ref[0, 0] += jnp.sum(nv)
-    sums_ref[0, 1] += jnp.sum(qv)
-    sums_ref[0, 2] += jnp.sum(nv * qv)
-    sums_ref[0, 3] += jnp.sum(qv * qv)
+    sums_ref[...] += jnp.concatenate(
+        [jnp.sum(t, axis=0, keepdims=True) for t in (nv, qv, nv * qv, qv * qv)],
+        axis=0)
 
 
 def _solve_from_sums(sums: jax.Array, n: int) -> Tuple[jax.Array, jax.Array]:
@@ -96,19 +100,19 @@ def _solve_from_sums(sums: jax.Array, n: int) -> Tuple[jax.Array, jax.Array]:
 
 
 def _col(x, sb: int) -> jax.Array:
-    """PVT scalar (scalar or per-stacked-entry) -> (SB, 1) f32."""
+    """PVT scalar (scalar or per-stacked-entry) -> (SB, 1, 1) f32."""
     x = jnp.asarray(x, jnp.float32)
     if x.size == sb:
-        return x.reshape(sb, 1)
-    return jnp.full((sb, 1), x.reshape(()))
+        return x.reshape(sb, 1, 1)
+    return jnp.full((sb, 1, 1), x.reshape(()))
 
 
 def _ccol(x, c: int, sb: int) -> jax.Array:
-    """Per-client PVT scalar (per-client or per-(client, entry)) -> (C, SB)."""
+    """Per-client PVT scalar (per-client or per-(client, entry)) -> (SB, C, 1, 1)."""
     x = jnp.asarray(x, jnp.float32)
     if x.size == c * sb:
-        return x.reshape(c, sb)
-    return jnp.broadcast_to(x.reshape(c, 1), (c, sb))
+        return x.reshape(c, sb).T.reshape(sb, c, 1, 1)
+    return jnp.broadcast_to(x.reshape(1, c, 1, 1), (sb, c, 1, 1))
 
 
 def fused_aggregate(
@@ -139,43 +143,51 @@ def fused_aggregate(
     m = int(srv_codes.size) // sb
     c = int(cl_codes.shape[0])
     m_pad = -(-m // TILE) * TILE
+    rows = m_pad // LANES
 
+    # Every block's last two dims are (TILE_ROWS, LANES) or the whole array
+    # dims; stacked entries and clients ride on squeezed leading axes.
     srv2 = srv_codes.reshape(sb, m).astype(fmt.container_dtype)
     cl2 = cl_codes.reshape(c, sb, m).astype(fmt.container_dtype)
-    srv2 = jnp.pad(srv2, ((0, 0), (0, m_pad - m)))
+    srv2 = jnp.pad(srv2, ((0, 0), (0, m_pad - m))).reshape(sb, rows, LANES)
     cl2 = jnp.pad(cl2, ((0, 0), (0, 0), (0, m_pad - m)))
+    cl2 = cl2.reshape(c, sb, rows, LANES)
     ss, sbias = _col(srv_s, sb), _col(srv_b, sb)
     cs, cb = _ccol(cl_s, c, sb), _ccol(cl_b, c, sb)
-    w2 = jnp.asarray(weights, jnp.float32).reshape(c, 1)
-    lr2 = jnp.full((1, 1), lr, jnp.float32)
+    w = jnp.asarray(weights, jnp.float32)
+    w3 = w.reshape(c, 1, 1)
+    k = jnp.stack([jnp.asarray(lr, jnp.float32),
+                   jnp.maximum(jnp.sum(w), 1e-9)]).reshape(1, 2)
 
-    grid = (sb, m_pad // TILE)
+    sq = pl.Squeezed()
+    grid = (sb, rows // TILE_ROWS)
     new_codes, sums = pl.pallas_call(
         functools.partial(_fused_kernel, fmt=fmt, m=m),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, TILE), lambda i, j: (i, j)),      # server codes
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),         # server s
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),         # server b
-            pl.BlockSpec((c, 1, TILE), lambda i, j: (0, i, j)),  # client codes
-            pl.BlockSpec((c, 1), lambda i, j: (0, i)),         # client s
-            pl.BlockSpec((c, 1), lambda i, j: (0, i)),         # client b
-            pl.BlockSpec((c, 1), lambda i, j: (0, 0)),         # weights
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),         # lr
+            pl.BlockSpec((sq, TILE_ROWS, LANES), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((sq, 1, 1), lambda i, j: (i, 0, 0)),      # server s
+            pl.BlockSpec((sq, 1, 1), lambda i, j: (i, 0, 0)),      # server b
+            pl.BlockSpec((c, sq, TILE_ROWS, LANES),
+                         lambda i, j: (0, i, j, 0)),               # client codes
+            pl.BlockSpec((sq, c, 1, 1), lambda i, j: (i, 0, 0, 0)),  # client s
+            pl.BlockSpec((sq, c, 1, 1), lambda i, j: (i, 0, 0, 0)),  # client b
+            pl.BlockSpec((c, 1, 1), lambda i, j: (0, 0, 0)),       # weights
+            pl.BlockSpec((1, 2), lambda i, j: (0, 0)),             # lr, Σw
         ],
         out_specs=[
-            pl.BlockSpec((1, TILE), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 4), lambda i, j: (i, 0)),
+            pl.BlockSpec((sq, TILE_ROWS, LANES), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((sq, 4, LANES), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((sb, m_pad), fmt.container_dtype),
-            jax.ShapeDtypeStruct((sb, 4), jnp.float32),
+            jax.ShapeDtypeStruct((sb, rows, LANES), fmt.container_dtype),
+            jax.ShapeDtypeStruct((sb, 4, LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(srv2, ss, sbias, cl2, cs, cb, w2, lr2)
+    )(srv2, ss, sbias, cl2, cs, cb, w3, k)
 
-    codes = new_codes[:, :m].reshape(shape)
-    s, b = _solve_from_sums(sums, m)
+    codes = new_codes.reshape(sb, m_pad)[:, :m].reshape(shape)
+    s, b = _solve_from_sums(jnp.sum(sums, axis=2), m)
     if batch_axes:
         bshape = shape[:batch_axes] + (1,) * (len(shape) - batch_axes)
         return codes, s.reshape(bshape), b.reshape(bshape)
@@ -190,13 +202,13 @@ def fused_aggregate_moved_bytes(
     A fused kernel reads each operand and writes each result exactly once;
     every f32 intermediate is tile-local VMEM, so the HBM traffic is the sum
     of the (padded) buffer sizes: (C+1) code planes in + 1 out, the per-entry
-    PVT scalars, the weights, and the [SB, 4] sums.
+    PVT scalars, the weights, and the per-lane [SB, 4, LANES] sums.
     """
     sb = stack_entries
     m = n // sb
     m_pad = -(-m // TILE) * TILE
     cb = fmt.container_bytes_per_value
     codes = (cohort + 1 + 1) * sb * m_pad * cb  # C client + 1 server in, 1 out
-    scalars = 4 * (2 * sb + 2 * cohort * sb + cohort + 1)  # s/b, weights, lr
-    sums = 4 * sb * 4
+    scalars = 4 * (2 * sb + 2 * cohort * sb + cohort + 2)  # s/b, w, lr, Σw
+    sums = 4 * sb * 4 * LANES
     return codes + scalars + sums

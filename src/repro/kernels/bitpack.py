@@ -10,7 +10,8 @@ work at HBM bandwidth:
   ``P_f = L // w`` consecutive fields occupies exactly ``P_w = L // 32``
   consecutive uint32 words, and *no field crosses a block boundary*.  Within
   a block the field -> (word, shift) mapping is a compile-time constant, so
-  both directions unroll into static column slices + scalar shifts:
+  both directions unroll into static row loads/stores + scalar shifts over
+  a fields-major layout (one superblock per lane):
 
   * pack:   word j ORs together the in-word contributions of the (statically
     known) fields that land in it — the same ``(f << sh)`` / ``(f >> (31-sh))
@@ -43,54 +44,72 @@ from jax.experimental import pallas as pl
 from repro.core.packing import packed_words
 
 _M32 = np.uint32(0xFFFFFFFF)
-# Target lane count per grid step; rounded so blocks stay row-aligned.
-_TARGET_LANES = 2048
+# Superblocks per grid step: the lane extent of one block.
+_STEP = 2048
 
 
 @functools.lru_cache(maxsize=None)
-def _geometry(width: int) -> Tuple[int, int, int]:
-    """(fields_per_block, words_per_block, block_rows_per_grid_step)."""
+def _geometry(width: int) -> Tuple[int, int]:
+    """(fields_per_block, words_per_block) of the w-bit superblock."""
     lcm = (32 * width) // math.gcd(32, width)
-    p_f = lcm // width
-    p_w = lcm // 32
-    rows = max(_TARGET_LANES // p_f, 1)
-    rows = -(-rows // 8) * 8  # sublane-aligned
-    return p_f, p_w, rows
+    return lcm // width, lcm // 32
+
+
+def _blocks(n: int, p_f: int) -> Tuple[int, int]:
+    """(padded superblock count, superblocks per grid step) for n fields.
+
+    The count is padded to whole grid steps of a multiple of 128 lanes."""
+    nblocks = -(-max(n, 1) // p_f)
+    lanes = min(_STEP, -(-nblocks // 128) * 128)
+    return -(-nblocks // lanes) * lanes, lanes
+
+
+# Both kernels work fields-major: a block is (P_f, lanes) fields or
+# (P_w, lanes) words, one superblock per lane, so HBM arrays are lane-dense
+# and every access is a static single-row ref load or store.  (A layout
+# with superblocks on rows, (rows, P_f) blocks sliced by lane, miscompiled
+# on TPU v5e: some packed words lost bits 16-23.)
 
 
 def _pack_kernel(f_ref, o_ref, *, width: int):
-    p_f, p_w, _ = _geometry(width)
-    f = f_ref[...]  # (R, P_f) uint32
-    cols = []
+    p_f, p_w = _geometry(width)
     for j in range(p_w):
         acc = None
         for i in range(p_f):
             word, sh = (i * width) // 32, (i * width) % 32
-            c = f[:, i : i + 1]
             if word == j:
-                term = (c << np.uint32(sh)) & _M32
+                term = f_ref[i : i + 1, :] << np.uint32(sh)
             elif word + 1 == j and sh + width > 32:  # field crosses into j
                 # field >> (32-sh) is UB at sh == 0; the two-step shift is safe
-                term = (c >> np.uint32(31 - sh)) >> np.uint32(1)
+                term = (f_ref[i : i + 1, :] >> np.uint32(31 - sh)) >> np.uint32(1)
             else:
                 continue
             acc = term if acc is None else (acc | term)
-        cols.append(acc)
-    o_ref[...] = jnp.concatenate(cols, axis=1)
+        o_ref[j : j + 1, :] = acc
 
 
 def _unpack_kernel(w_ref, o_ref, *, width: int):
-    p_f, p_w, _ = _geometry(width)
+    p_f, p_w = _geometry(width)
     mask = np.uint32((1 << width) - 1) if width < 32 else _M32
-    w = w_ref[...]  # (R, P_w) uint32
-    cols = []
     for i in range(p_f):
         word, sh = (i * width) // 32, (i * width) % 32
-        lo = w[:, word : word + 1] >> np.uint32(sh)
+        lo = w_ref[word : word + 1, :] >> np.uint32(sh)
         nxt = min(word + 1, p_w - 1)  # edge clamp; high bits masked off below
-        hi = (w[:, nxt : nxt + 1] << np.uint32(31 - sh)) << np.uint32(1)
-        cols.append((lo | hi) & mask)
-    o_ref[...] = jnp.concatenate(cols, axis=1)
+        hi = (w_ref[nxt : nxt + 1, :] << np.uint32(31 - sh)) << np.uint32(1)
+        o_ref[i : i + 1, :] = (lo | hi) & mask
+
+
+def _call(kernel, x, rows_in: int, rows_out: int, lanes: int, width: int,
+          interpret: bool) -> jax.Array:
+    nblocks = x.shape[1]
+    return pl.pallas_call(
+        functools.partial(kernel, width=width),
+        grid=(nblocks // lanes,),
+        in_specs=[pl.BlockSpec((rows_in, lanes), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((rows_out, lanes), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((rows_out, nblocks), jnp.uint32),
+        interpret=interpret,
+    )(x)
 
 
 def pack(codes: jax.Array, width: int, *, interpret: bool = False) -> jax.Array:
@@ -100,49 +119,34 @@ def pack(codes: jax.Array, width: int, *, interpret: bool = False) -> jax.Array:
     """
     if not (1 <= width <= 32):
         raise ValueError(f"width must be in [1, 32], got {width}")
-    p_f, p_w, rows = _geometry(width)
+    p_f, p_w = _geometry(width)
     flat = codes.reshape(-1).astype(jnp.uint32)
     n = flat.shape[0]
-    nblocks = -(-max(n, 1) // p_f)
-    nblocks = -(-nblocks // rows) * rows
+    nblocks, lanes = _blocks(n, p_f)
     flat = jnp.pad(flat, (0, nblocks * p_f - n))
-    out = pl.pallas_call(
-        functools.partial(_pack_kernel, width=width),
-        grid=(nblocks // rows,),
-        in_specs=[pl.BlockSpec((rows, p_f), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((rows, p_w), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, p_w), jnp.uint32),
-        interpret=interpret,
-    )(flat.reshape(nblocks, p_f))
-    return out.reshape(-1)[: packed_words(n, width)]
+    out = _call(_pack_kernel, flat.reshape(nblocks, p_f).T, p_f, p_w, lanes,
+                width, interpret)
+    return out.T.reshape(-1)[: packed_words(n, width)]
 
 
 def unpack(words: jax.Array, width: int, n: int, *, interpret: bool = False) -> jax.Array:
     """Inverse of :func:`pack`: recover ``n`` codes of ``width`` bits (uint32)."""
     if not (1 <= width <= 32):
         raise ValueError(f"width must be in [1, 32], got {width}")
-    p_f, p_w, rows = _geometry(width)
+    p_f, p_w = _geometry(width)
     flat = words.reshape(-1).astype(jnp.uint32)
-    nblocks = -(-max(n, 1) // p_f)
-    nblocks = -(-nblocks // rows) * rows
+    nblocks, lanes = _blocks(n, p_f)
     # Zero tail padding == the oracle's appended zero word.
     flat = jnp.pad(flat, (0, nblocks * p_w - flat.shape[0]))
-    out = pl.pallas_call(
-        functools.partial(_unpack_kernel, width=width),
-        grid=(nblocks // rows,),
-        in_specs=[pl.BlockSpec((rows, p_w), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((rows, p_f), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, p_f), jnp.uint32),
-        interpret=interpret,
-    )(flat.reshape(nblocks, p_w))
-    return out.reshape(-1)[:n]
+    out = _call(_unpack_kernel, flat.reshape(nblocks, p_w).T, p_w, p_f, lanes,
+                width, interpret)
+    return out.T.reshape(-1)[:n]
 
 
 def pack_moved_bytes(n: int, width: int) -> int:
     """HBM bytes the pack kernel actually moves (padded operands + result)."""
-    p_f, p_w, rows = _geometry(width)
-    nblocks = -(-max(n, 1) // p_f)
-    nblocks = -(-nblocks // rows) * rows
+    p_f, p_w = _geometry(width)
+    nblocks, _ = _blocks(n, p_f)
     return 4 * nblocks * p_f + 4 * nblocks * p_w
 
 

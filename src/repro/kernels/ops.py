@@ -6,11 +6,15 @@ Dispatch policy (DESIGN.md §13):
     or interpret-mode Pallas when ``force_interpret=True`` (used by the
     correctness tests, which execute the actual kernel bodies).
 
-The platform probe runs ONCE at import and is memoized in ``_ON_TPU``.
-It used to be a per-call function that swallowed every exception — inside a
-jit trace a probe failure silently returned False and could flip dispatch
-between retraces; now the decision is a module constant (regression-tested
-in tests/test_kernels.py::test_cpu_dispatch_hits_ref).
+The backend is decided ONCE per process, lazily at the first dispatch,
+from ``jax.default_backend()`` and memoized by :func:`_on_tpu`.  Importing
+this module touches no device state, so a process that only imports
+``repro.kernels`` never takes the chip.  The probe catches nothing: a
+backend that cannot be initialized raises instead of silently routing every
+kernel to the jnp oracles, and the memo keeps dispatch fixed between
+retraces (regression-tested in tests/test_kernels.py).  In a TPU process a
+kernel never takes the ref or interpret branch unless the caller passes
+``force_interpret=True``.
 
 Every branch also bumps a **dispatch counter** keyed ``(op, backend)``
 with backend ∈ {pallas, interpret, ref} (DESIGN.md §15).  The wrappers
@@ -39,14 +43,17 @@ from . import quantize as _q
 from . import ref
 
 
-def _probe_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+@functools.cache
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
-_ON_TPU: bool = _probe_tpu()
+def __getattr__(name: str):
+    # ``ops._ON_TPU`` reads the memoized decision (deciding it if needed).
+    if name == "_ON_TPU":
+        return _on_tpu()
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
 
 _DISPATCHES: Counter = Counter()
 
@@ -66,8 +73,8 @@ def reset_dispatch_counts() -> None:
 
 def _dispatch(op: str, force_interpret: bool) -> str:
     """Pick + record the backend for one traced specialization."""
-    backend = ("pallas" if _ON_TPU
-               else "interpret" if force_interpret else "ref")
+    backend = ("interpret" if force_interpret
+               else "pallas" if _on_tpu() else "ref")
     _record(op, backend)
     return backend
 

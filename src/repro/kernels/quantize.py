@@ -68,10 +68,11 @@ def _quantize_stats_kernel(x_ref, o_ref, sums_ref, *, fmt: FloatFormat):
     codes = _jnp_encode(x, fmt, quantize=True)
     o_ref[...] = codes
     q = _jnp_decode(codes, fmt)
-    sums_ref[0, 0] += jnp.sum(x)
-    sums_ref[0, 1] += jnp.sum(q)
-    sums_ref[0, 2] += jnp.sum(x * q)
-    sums_ref[0, 3] += jnp.sum(q * q)
+    # Per-lane partial sums (Mosaic cannot store scalars to VMEM); the
+    # lanes are summed outside the kernel.
+    sums_ref[...] += jnp.concatenate(
+        [jnp.sum(t, axis=0, keepdims=True) for t in (x, q, x * q, q * q)],
+        axis=0)
 
 
 def quantize(x: jax.Array, fmt: FloatFormat, *, interpret: bool = False) -> jax.Array:
@@ -128,12 +129,12 @@ def quantize_stats(x: jax.Array, fmt: FloatFormat, *, interpret: bool = False):
         in_specs=[pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 4), lambda i: (0, 0)),
+            pl.BlockSpec((4, LANES), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), fmt.container_dtype),
-            jax.ShapeDtypeStruct((1, 4), jnp.float32),
+            jax.ShapeDtypeStruct((4, LANES), jnp.float32),
         ],
         interpret=interpret,
     )(x2)
-    return codes.reshape(-1)[:n].reshape(x.shape), sums[0]
+    return codes.reshape(-1)[:n].reshape(x.shape), jnp.sum(sums, axis=1)

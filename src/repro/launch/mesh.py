@@ -13,26 +13,18 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where the installed jax
-    supports them (jax.sharding.AxisType landed after 0.4.37; older
-    versions default every axis to Auto anyway)."""
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small host mesh for tests (requires >= data*model local devices)."""
-    return compat_make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def make_population_mesh(num_shards=None):
@@ -49,7 +41,8 @@ def make_population_mesh(num_shards=None):
     n = len(jax.devices())
     if num_shards is not None:
         n = max(1, min(int(num_shards), n))
-    return compat_make_mesh((n,), ("clients",))
+    return jax.make_mesh((n,), ("clients",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 # TPU v5e hardware constants (per chip) — used by the roofline analysis.

@@ -28,6 +28,7 @@ from repro.api.session import ServeSession
 from repro.configs.registry import get_arch
 from repro.core.omc import OMCConfig
 from repro.federated.state import compress_params
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get_family, is_servable
 from repro.obs.log import Logger
 
@@ -46,6 +47,7 @@ def main():
     ap.add_argument("--quiet", action="store_true",
                     help="suppress stderr text")
     args = ap.parse_args()
+    enable_compile_cache()
     log = Logger(quiet=args.quiet)
 
     arch = get_arch(args.arch)

@@ -34,6 +34,7 @@ from repro.core.omc import OMCConfig
 from repro.data.synthetic import make_frame_task, make_lm_task
 from repro.federated.round import make_round_fn
 from repro.federated.state import init_state, state_bytes_report
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get_family
 from repro.optim import fedavg
 
@@ -69,6 +70,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     cfg = arch.smoke_config() if args.smoke else arch.config()

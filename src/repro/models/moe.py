@@ -48,15 +48,6 @@ from .common import (
 )
 from .transformer import TransformerConfig, _embed_lookup, _qkv, param_specs as _dense_param_specs
 
-# jax.shard_map (with check_vma) replaced jax.experimental.shard_map
-# (check_rep) after 0.4.x; support both so host-mesh tests run everywhere.
-if hasattr(jax, "shard_map"):
-    _shard_map, _SM_KW = jax.shard_map, {"check_vma": False}
-else:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SM_KW = {"check_rep": False}
-
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig(TransformerConfig):
@@ -267,12 +258,12 @@ def moe_ffn(x: jax.Array, w: Dict[str, jax.Array], cfg: MoEConfig):
 
     xspec = P(batch_spec, None, None)
     wspec_ = P("model", None, None)
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(xspec, P(None, None), wspec_, wspec_, wspec_),
         out_specs=(xspec, P()),
-        **_SM_KW,
+        check_vma=False,
     )(x, w["router"], w["w1"], w["w3"], w["w2"])
     return y.astype(x.dtype), aux
 

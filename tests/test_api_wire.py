@@ -66,6 +66,47 @@ def test_roundtrip_bit_exact(fmt):
 
 
 @pytest.mark.parametrize("fmt", FORMATS)
+def test_chunked_pack_is_byte_identical(fmt, monkeypatch):
+    """Leaves larger than one device call are packed and unpacked chunk by
+    chunk: the payload stays byte-identical and decodes code-for-code, for
+    full leaves, odd tails and delta leaves alike."""
+    omc = OMCConfig.parse(fmt, policy=POLICY)
+    ct = compress_tree(_tree(), omc.fmt, omc.policy)
+    t2 = dict(_tree())
+    t2["emb"] = t2["emb"].at[:3].add(0.5)
+    ct2 = compress_tree(t2, omc.fmt, omc.policy)
+    whole = codecs.encode_payload(ct)
+    whole_delta = codecs.encode_payload(ct2, base=ct)
+    monkeypatch.setattr(codecs, "_CHUNK_FIELDS", 96)  # 2048 = 21*96 + 32
+    assert codecs.encode_payload(ct) == whole
+    assert codecs.encode_payload(ct2, base=ct) == whole_delta
+    assert_trees_bit_equal(ct, codecs.decode_payload(whole)[0])
+    assert_trees_bit_equal(ct2, codecs.decode_payload(whole_delta, base=ct)[0])
+
+
+def test_encode_and_digest_release_leaves_without_gc():
+    """Walking a tree leaves no reference cycle behind: a dropped tree's
+    leaves are freed at once, not when the garbage collector next runs (a
+    model's codes would otherwise stay on the device after an encode, a
+    digest or a hot swap)."""
+    import gc
+    import weakref
+
+    tree = dict(a=np.arange(64, dtype=np.float32),
+                b=[np.ones(3, np.float32), (np.zeros(2, np.float32),)])
+    refs = [weakref.ref(x) for x in (tree["a"], tree["b"][0], tree["b"][1][0])]
+    gc.disable()
+    try:
+        codecs.encode_payload(tree)
+        codecs.tree_digest(tree)
+        codecs.decode_payload(codecs.encode_payload(tree), base=tree)
+        del tree
+        assert all(r() is None for r in refs)
+    finally:
+        gc.enable()
+
+
+@pytest.mark.parametrize("fmt", FORMATS)
 def test_body_bytes_reconcile_with_store_accounting(fmt):
     omc = OMCConfig.parse(fmt, policy=POLICY)
     ct = compress_tree(_tree(), omc.fmt, omc.policy)

@@ -173,7 +173,7 @@ def test_fused_aggregate_pvt_off_returns_identity_affine():
 
 
 def test_cpu_dispatch_hits_ref(monkeypatch):
-    assert isinstance(ops._ON_TPU, bool)  # memoized at import, not a callable
+    assert ops._on_tpu() is ops._on_tpu()  # decided lazily once, then memoized
     if ops._ON_TPU:
         pytest.skip("host has a TPU: the compiled-Pallas branch is correct")
     calls = []
@@ -246,3 +246,56 @@ def test_dequant_matmul_bias_rank1_correction():
     want = a @ w_eff
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+_IMPORT_SCRIPT = r"""
+import jax
+from jax._src import xla_bridge
+
+def _boom(*a, **k):
+    raise AssertionError("device state touched while importing repro.kernels")
+
+jax.devices = jax.local_devices = jax.default_backend = _boom
+import repro.kernels
+from repro.kernels import ops
+assert not xla_bridge.backends_are_initialized()
+print("IMPORT-OK")
+"""
+
+
+def test_import_touches_no_device_state():
+    """Importing repro.kernels must not take the chip (one process per
+    chip): the backend is decided at the first dispatch, not at import."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__))), "src")
+    r = subprocess.run([sys.executable, "-c", _IMPORT_SCRIPT],
+                       capture_output=True, text=True, timeout=300,
+                       env={"PYTHONPATH": src, "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
+    assert "IMPORT-OK" in r.stdout, r.stdout + r.stderr
+
+
+def test_backend_probe_error_propagates(monkeypatch):
+    """A backend that cannot be probed raises; it never silently selects
+    the ref oracles."""
+    def boom():
+        raise RuntimeError("backend probe failed")
+
+    monkeypatch.setattr(jax, "default_backend", boom)
+    ops._on_tpu.cache_clear()
+    try:
+        with pytest.raises(RuntimeError, match="backend probe failed"):
+            ops.pack_bits(jnp.arange(4127, dtype=jnp.uint32) & np.uint32(7), 3)
+    finally:
+        ops._on_tpu.cache_clear()
+
+
+def test_tpu_dispatch_never_takes_ref(monkeypatch):
+    """On a TPU process every op picks the compiled kernel; only an explicit
+    force_interpret selects interpret mode."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    assert ops._dispatch("probe_op", False) == "pallas"
+    assert ops._dispatch("probe_op", True) == "interpret"

@@ -1,9 +1,9 @@
 """launch/ mesh + sharding-spec unit tests (ISSUE 9 satellite).
 
 The production mesh shapes (16x16, 2x16x16) exceed any test host, so
-``make_production_mesh`` / the compat shim are tested by monkeypatching
-``jax.make_mesh`` and capturing the arguments; host- and population-mesh
-tests run for real on the local devices.
+``make_production_mesh`` is tested by monkeypatching ``jax.make_mesh`` and
+capturing the arguments; host- and population-mesh tests run for real on
+the local devices.
 """
 
 import jax
@@ -24,18 +24,11 @@ class _Capture:
         return ("mesh", tuple(shape), tuple(axes))
 
 
-def test_compat_make_mesh_axis_types(monkeypatch):
-    """When jax.sharding.AxisType exists every axis is explicitly Auto;
-    otherwise no kwargs are passed (older jax defaults to Auto anyway)."""
-    cap = _Capture()
-    monkeypatch.setattr(jax, "make_mesh", cap)
-    mesh_lib.compat_make_mesh((2, 3), ("data", "model"))
-    (shape, axes, kw), = cap.calls
-    assert shape == (2, 3) and axes == ("data", "model")
-    if hasattr(jax.sharding, "AxisType"):
-        assert kw == {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-    else:
-        assert kw == {}
+def test_meshes_have_auto_axis_types():
+    """Every mesh this module builds marks each axis explicitly Auto."""
+    auto = jax.sharding.AxisType.Auto
+    assert mesh_lib.make_host_mesh().axis_types == (auto, auto)
+    assert mesh_lib.make_population_mesh().axis_types == (auto,)
 
 
 def test_make_production_mesh_shapes(monkeypatch):
@@ -104,3 +97,26 @@ def test_population_mesh_hosts_store_rows():
     mesh = mesh_lib.make_population_mesh(num_shards=2)
     rows = store.device_ef(mesh)
     assert rows and all(v.shape[0] == 4 for v in rows.values())
+
+
+def test_compile_cache_env_var_wins(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert calls == []  # JAX reads the variable itself
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = compile_cache.enable_compile_cache()
+    root = Path(__file__).resolve().parents[1]
+    assert Path(path) == root / ".jax_cache"
+    assert calls == [("jax_compilation_cache_dir", path)]
+    assert ".jax_cache/" in (root / ".gitignore").read_text().splitlines()

@@ -1,5 +1,6 @@
 """MoE dispatch: shard_map path == single-device reference; capacity rules."""
 
+import os
 import subprocess
 import sys
 
@@ -8,9 +9,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models import moe
 from repro.models.common import activate_mesh
+
+SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                   "src")
 
 CFG = moe.MoEConfig(n_layers=1, d_model=32, n_heads=4, n_kv_heads=2,
                     d_ff=64, vocab=64, n_experts=4, top_k=2)
@@ -25,7 +29,7 @@ def test_shard_map_matches_reference_1x1():
     w = _ffn_weights(jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
     y_ref, aux_ref = moe.moe_ffn(x, w, CFG)
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh(1, 1)
     with activate_mesh(mesh):
         y_sm, aux_sm = jax.jit(lambda x, w: moe.moe_ffn(x, w, CFG))(x, w)
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_sm),
@@ -38,7 +42,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, numpy as np
 import jax.numpy as jnp
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models import moe
 from repro.models.common import activate_mesh
 
@@ -48,7 +52,7 @@ blk = moe._block_init(jax.random.PRNGKey(0), cfg)
 w = {k: blk[k] for k in ("router", "w1", "w3", "w2")}
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
 y_ref, aux_ref = moe.moe_ffn(x, w, cfg)
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(2, 4)
 with activate_mesh(mesh):
     y_sm, aux_sm = jax.jit(lambda x, w: moe.moe_ffn(x, w, cfg))(x, w)
 # capacity differs per-shard (T_local < T), so token drops may differ around
@@ -65,8 +69,9 @@ def test_shard_map_matches_reference_8dev():
     r = subprocess.run(
         [sys.executable, "-c", _MULTIDEV_SCRIPT],
         capture_output=True, text=True,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-        cwd="/root/repo", timeout=600,
+        env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu"},
+        timeout=600,
     )
     assert "MULTIDEV-OK" in r.stdout, r.stdout + r.stderr
 
