@@ -66,12 +66,14 @@ import struct
 import zlib
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import packing
 from repro.core.formats import FloatFormat
 from repro.core.store import CompressedVariable, is_compressed
+from repro.obs import null_span
 
 MAGIC = b"OMCW"
 WIRE_VERSION = 1
@@ -88,6 +90,27 @@ _PVT_BYTES_PER_ENTRY = 8  # s and b, f32 each
 # the size of the leaf, and the TPU compile time of each call's shape
 # (which grows with the call's size: 2 s at 2^18 fields, 45 s at 2^22).
 _CHUNK_FIELDS = 1 << 18
+
+# Host spans (DESIGN.md §15), ``omc.<name>`` in a profiler trace: one
+# ``codec.encode`` / ``codec.decode`` a payload; ``codec.d2h`` around every
+# read of a device array to the host; ``codec.pack`` / ``codec.unpack``
+# around each chunk's kernel call, which uploads the chunk as part of the
+# call (a separate upload first costs a host copy and the Python of
+# ``device_put`` for every chunk); ``codec.h2d`` around every other upload.
+
+
+def _to_host(x, dtype=None) -> np.ndarray:
+    """``np.asarray(x, dtype)``; a device array's read is a ``codec.d2h``
+    span."""
+    if isinstance(x, jax.Array):
+        with null_span(None, "codec.d2h"):
+            return np.asarray(x, dtype)
+    return np.asarray(x, dtype)
+
+
+def _to_device(x, dtype=None) -> jax.Array:
+    with null_span(None, "codec.h2d"):
+        return jnp.asarray(x, dtype)
 
 
 class CodecError(ValueError):
@@ -290,16 +313,16 @@ def tree_digest(tree) -> int:
             for c in chunks:
                 h = zlib.crc32(c, h)
         elif is_compressed(leaf):
-            h = zlib.crc32(np.ascontiguousarray(np.asarray(leaf.codes)).tobytes(), h)
+            h = zlib.crc32(np.ascontiguousarray(_to_host(leaf.codes)).tobytes(), h)
             h = zlib.crc32(
-                np.ascontiguousarray(np.asarray(leaf.s, np.float32)).tobytes(), h
+                np.ascontiguousarray(_to_host(leaf.s, np.float32)).tobytes(), h
             )
             h = zlib.crc32(
-                np.ascontiguousarray(np.asarray(leaf.b, np.float32)).tobytes(), h
+                np.ascontiguousarray(_to_host(leaf.b, np.float32)).tobytes(), h
             )
             h = zlib.crc32(leaf.fmt.name.encode(), h)
         else:
-            h = zlib.crc32(np.ascontiguousarray(np.asarray(leaf)).tobytes(), h)
+            h = zlib.crc32(np.ascontiguousarray(_to_host(leaf)).tobytes(), h)
     return h
 
 
@@ -309,35 +332,46 @@ def tree_digest(tree) -> int:
 
 
 def _codes_np(cv: CompressedVariable) -> np.ndarray:
-    return np.asarray(cv.codes).reshape(-1)
+    return _to_host(cv.codes).reshape(-1)
+
+
+def _pack_chunk(codes: np.ndarray, bits: int) -> np.ndarray:
+    with null_span(None, "codec.pack"):  # uploads the chunk as it calls
+        words = packing.pack(codes, bits)
+    return _to_host(words, np.uint32)
+
+
+def _unpack_chunk(words: np.ndarray, bits: int, n: int) -> np.ndarray:
+    with null_span(None, "codec.unpack"):  # uploads the chunk as it calls
+        codes = packing.unpack(words, bits, n)
+    return _to_host(codes, np.uint32)
 
 
 def _pack_np(codes_flat: np.ndarray, bits: int) -> np.ndarray:
     n = codes_flat.size
     if n <= _CHUNK_FIELDS:
-        return np.asarray(packing.pack(codes_flat, bits), np.uint32)
+        return _pack_chunk(codes_flat, bits)
     return np.concatenate([
-        np.asarray(packing.pack(codes_flat[i:i + _CHUNK_FIELDS], bits),
-                   np.uint32)
+        _pack_chunk(codes_flat[i:i + _CHUNK_FIELDS], bits)
         for i in range(0, n, _CHUNK_FIELDS)
     ])
 
 
 def _unpack_np(words: np.ndarray, bits: int, n: int) -> np.ndarray:
     if n <= _CHUNK_FIELDS:
-        return np.asarray(packing.unpack(words, bits, n), np.uint32)
+        return _unpack_chunk(words, bits, n)
     step = _CHUNK_FIELDS * bits // 32  # words per full chunk
     return np.concatenate([
-        np.asarray(packing.unpack(words[k * step:(k + 1) * step], bits,
-                                  min(_CHUNK_FIELDS, n - i)), np.uint32)
+        _unpack_chunk(words[k * step:(k + 1) * step], bits,
+                      min(_CHUNK_FIELDS, n - i))
         for k, i in enumerate(range(0, n, _CHUNK_FIELDS))
     ])
 
 
 def _encode_omc(cv: CompressedVariable, base) -> Tuple[Dict[str, Any], List[bytes]]:
     fmt = cv.fmt
-    s = np.ascontiguousarray(np.asarray(cv.s, np.float32))
-    b = np.ascontiguousarray(np.asarray(cv.b, np.float32))
+    s = np.ascontiguousarray(_to_host(cv.s, np.float32))
+    b = np.ascontiguousarray(_to_host(cv.b, np.float32))
     codes = _codes_np(cv)
     meta = dict(
         kind="omc",
@@ -375,7 +409,7 @@ def _encode_omc(cv: CompressedVariable, base) -> Tuple[Dict[str, Any], List[byte
 
 
 def _encode_raw(leaf, base) -> Tuple[Dict[str, Any], List[bytes]]:
-    arr = np.ascontiguousarray(np.asarray(leaf))
+    arr = np.ascontiguousarray(_to_host(leaf))
     meta = dict(
         kind="raw",
         dtype=arr.dtype.str,
@@ -386,12 +420,12 @@ def _encode_raw(leaf, base) -> Tuple[Dict[str, Any], List[bytes]]:
         base is not None
         and not is_compressed(base)
         and hasattr(base, "dtype")
-        and np.asarray(base).dtype == arr.dtype
-        and np.asarray(base).shape == arr.shape
+        and np.dtype(base.dtype) == arr.dtype
+        and np.shape(base) == arr.shape
         and arr.dtype.itemsize == 4
     ):
         xor = arr.view(np.uint32).reshape(-1) ^ np.ascontiguousarray(
-            np.asarray(base)
+            _to_host(base)
         ).view(np.uint32).reshape(-1)
         (idx,) = np.nonzero(xor)
         if 8 * idx.size < arr.nbytes:
@@ -437,9 +471,9 @@ def _decode_omc(meta: Dict[str, Any], body: memoryview, off: int, base):
         off += 4 * nwords
         codes = _unpack_np(words, fmt.bits, n)
     cv = CompressedVariable(
-        jnp.asarray(codes.reshape(shape).astype(np.dtype(fmt.container_dtype))),
-        jnp.asarray(s.reshape(sb_shape), jnp.float32),
-        jnp.asarray(b.reshape(sb_shape), jnp.float32),
+        _to_device(codes.reshape(shape).astype(np.dtype(fmt.container_dtype))),
+        _to_device(s.reshape(sb_shape), jnp.float32),
+        _to_device(b.reshape(sb_shape), jnp.float32),
         fmt,
     )
     return cv, off
@@ -452,7 +486,7 @@ def _decode_raw(meta: Dict[str, Any], body: memoryview, off: int, base):
     if meta["mode"] == "delta":
         if base is None or is_compressed(base):
             raise CodecError("delta leaf but no matching raw base was supplied")
-        barr = np.ascontiguousarray(np.asarray(base))
+        barr = np.ascontiguousarray(_to_host(base))
         if barr.dtype != dtype or barr.shape != shape:
             raise CodecError("delta base mismatch (dtype or shape)")
         bits = barr.view(np.uint32).reshape(-1).copy()
@@ -467,7 +501,7 @@ def _decode_raw(meta: Dict[str, Any], body: memoryview, off: int, base):
     else:
         arr = np.frombuffer(body, dtype, n, off).reshape(shape)
         off += dtype.itemsize * n
-    return jnp.asarray(arr), off
+    return _to_device(arr), off
 
 
 # ---------------------------------------------------------------------------
@@ -490,42 +524,43 @@ def encode_payload(tree, *, base=None, round_index: int = 0,
     automatically.  Untagged frames (the plain OMC path) stay
     byte-identical to wire version 1 payloads.
     """
-    base_leaves: Dict[str, Any] = {}
-    if base is not None:
-        base_leaves = {_path_key(p): leaf for p, leaf in _flatten(base)}
+    with null_span(None, "codec.encode"):
+        base_leaves: Dict[str, Any] = {}
+        if base is not None:
+            base_leaves = {_path_key(p): leaf for p, leaf in _flatten(base)}
 
-    manifest: List[Dict[str, Any]] = []
-    chunks: List[bytes] = []
-    any_delta = False
-    kinds_seen = set()
-    for parts, leaf in _flatten(tree):
-        bleaf = base_leaves.get(_path_key(parts))
-        if is_compressed(leaf):
-            meta, ch = _encode_omc(leaf, bleaf)
-        elif (kind := _leaf_kind(leaf)) is not None:
-            meta, ch = _LEAF_CODECS[kind][1](leaf, bleaf)
-            kinds_seen.add(kind)
-        else:
-            meta, ch = _encode_raw(leaf, bleaf)
-        any_delta |= meta["mode"] == "delta"
-        meta["path"] = parts
-        manifest.append(meta)
-        chunks.extend(ch)
+        manifest: List[Dict[str, Any]] = []
+        chunks: List[bytes] = []
+        any_delta = False
+        kinds_seen = set()
+        for parts, leaf in _flatten(tree):
+            bleaf = base_leaves.get(_path_key(parts))
+            if is_compressed(leaf):
+                meta, ch = _encode_omc(leaf, bleaf)
+            elif (kind := _leaf_kind(leaf)) is not None:
+                meta, ch = _LEAF_CODECS[kind][1](leaf, bleaf)
+                kinds_seen.add(kind)
+            else:
+                meta, ch = _encode_raw(leaf, bleaf)
+            any_delta |= meta["mode"] == "delta"
+            meta["path"] = parts
+            manifest.append(meta)
+            chunks.extend(ch)
 
-    frame: Dict[str, Any] = dict(leaves=manifest)
-    tag = _strategy_tag(strategy, kinds_seen)
-    if tag is not None:
-        frame["strategy"], frame["strategy_version"] = tag
-    mjson = json.dumps(frame, separators=(",", ":")).encode()
-    body = b"".join(chunks)
-    flags = FLAG_DELTA if any_delta else 0
-    digest = tree_digest(base) if any_delta else 0
-    crc = zlib.crc32(body, zlib.crc32(mjson))
-    header = _HEADER.pack(
-        MAGIC, WIRE_VERSION, flags, int(round_index), len(mjson), len(body),
-        crc, digest,
-    )
-    return header + mjson + body
+        frame: Dict[str, Any] = dict(leaves=manifest)
+        tag = _strategy_tag(strategy, kinds_seen)
+        if tag is not None:
+            frame["strategy"], frame["strategy_version"] = tag
+        mjson = json.dumps(frame, separators=(",", ":")).encode()
+        body = b"".join(chunks)
+        flags = FLAG_DELTA if any_delta else 0
+        digest = tree_digest(base) if any_delta else 0
+        crc = zlib.crc32(body, zlib.crc32(mjson))
+        header = _HEADER.pack(
+            MAGIC, WIRE_VERSION, flags, int(round_index), len(mjson), len(body),
+            crc, digest,
+        )
+        return header + mjson + body
 
 
 def _strategy_tag(strategy, kinds_seen) -> Optional[Tuple[str, int]]:
@@ -620,40 +655,43 @@ def decode_payload(data: bytes, *, base=None) -> Tuple[Any, PayloadInfo]:
     `CodecError` instead of silently producing corrupt parameters.  For full
     payloads ``base`` is ignored, so callers may always pass what they hold.
     """
-    info, manifest, body = _parse_frame(data)
-    if info.is_delta:
-        if base is None:
-            raise CodecError(
-                "delta payload requires the base tree it was built on"
-            )
-        if tree_digest(base) != info.base_digest:
-            raise CodecError(
-                "delta base mismatch: payload was encoded against a different "
-                "tree than the one supplied (stale or wrong-round base)"
-            )
-    base_leaves: Dict[str, Any] = {}
-    if base is not None:
-        base_leaves = {_path_key(p): leaf for p, leaf in _flatten(base)}
+    with null_span(None, "codec.decode"):
+        info, manifest, body = _parse_frame(data)
+        if info.is_delta:
+            if base is None:
+                raise CodecError(
+                    "delta payload requires the base tree it was built on"
+                )
+            if tree_digest(base) != info.base_digest:
+                raise CodecError(
+                    "delta base mismatch: payload was encoded against a "
+                    "different tree than the one supplied (stale or "
+                    "wrong-round base)"
+                )
+        base_leaves: Dict[str, Any] = {}
+        if base is not None:
+            base_leaves = {_path_key(p): leaf for p, leaf in _flatten(base)}
 
-    entries = []
-    off = 0
-    for meta in manifest["leaves"]:
-        parts = [list(p) for p in meta["path"]]
-        bleaf = base_leaves.get(_path_key(parts))
-        if meta["kind"] == "omc":
-            leaf, off = _decode_omc(meta, body, off, bleaf)
-        elif meta["kind"] == "raw":
-            leaf, off = _decode_raw(meta, body, off, bleaf)
-        else:
-            if meta["kind"] not in _LEAF_CODECS:
-                _ensure_strategy_codecs()
-            if meta["kind"] not in _LEAF_CODECS:
-                raise CodecError(f"unknown leaf kind {meta['kind']!r}")
-            leaf, off = _LEAF_CODECS[meta["kind"]][2](meta, body, off, bleaf)
-        entries.append((parts, leaf))
-    if off != info.body_bytes:
-        raise CodecError(f"body length mismatch: consumed {off}, have {info.body_bytes}")
-    return _unflatten(entries), info
+        entries = []
+        off = 0
+        for meta in manifest["leaves"]:
+            parts = [list(p) for p in meta["path"]]
+            bleaf = base_leaves.get(_path_key(parts))
+            if meta["kind"] == "omc":
+                leaf, off = _decode_omc(meta, body, off, bleaf)
+            elif meta["kind"] == "raw":
+                leaf, off = _decode_raw(meta, body, off, bleaf)
+            else:
+                if meta["kind"] not in _LEAF_CODECS:
+                    _ensure_strategy_codecs()
+                if meta["kind"] not in _LEAF_CODECS:
+                    raise CodecError(f"unknown leaf kind {meta['kind']!r}")
+                leaf, off = _LEAF_CODECS[meta["kind"]][2](meta, body, off, bleaf)
+            entries.append((parts, leaf))
+        if off != info.body_bytes:
+            raise CodecError(f"body length mismatch: consumed {off}, "
+                             f"have {info.body_bytes}")
+        return _unflatten(entries), info
 
 
 def payload_bytes_report(tree) -> Dict[str, Any]:

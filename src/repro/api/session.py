@@ -561,10 +561,12 @@ class ServeSession:
                                              dtype=dtype)
 
     def prefill(self, batch, cache):
-        return self._prefill(self.storage, batch, cache)
+        with null_span(self.obs, "serve.prefill"):
+            return self._prefill(self.storage, batch, cache)
 
     def decode_step(self, cache, tokens):
-        return self._decode(self.storage, cache, tokens)
+        with null_span(self.obs, "serve.decode_step"):
+            return self._decode(self.storage, cache, tokens)
 
     def generate(self, batch, cache, steps: int, *,
                  sample: Callable[[jax.Array], jax.Array] = None):

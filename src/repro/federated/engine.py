@@ -305,6 +305,14 @@ def apply_server_step(server_f32, mean_model, specs, omc: OMCConfig,
 # all tiers, one XLA program.
 # ---------------------------------------------------------------------------
 
+#: Named scopes of the round program (DESIGN.md §15).  Side by side, never
+#: nested, they cover the whole program; a device op's ``tf_op`` path in a
+#: profiler trace names the one it belongs to.
+DECOMPRESS = "omc.decompress"
+CLIENT = "omc.client"
+TRANSPORT_ENCODE = "omc.transport_encode"
+SERVER_STEP = "omc.server_step"
+
 
 def _run_cohort(one, server_f32, batches, round_index, ids,
                 client_chunk: Optional[int], ef_rows=None):
@@ -443,28 +451,34 @@ def make_round_fn(
         # Compressed-domain server round (§13): selected variables never
         # exist as an f32 cohort stack on the server — each client row is
         # transport-encoded and the fused kernel aggregates codes directly.
-        w = alive.astype(jnp.float32)
-        loss_c = jnp.where(alive, loss_c, 0.0)
-        n_alive = w.sum()
-        loss = (loss_c * w).sum() / jnp.maximum(n_alive, 1.0)
+        # Each leaf enters the encode scope and then the server-step scope,
+        # so the two stay side by side in the trace in the ops' own order.
+        with jax.named_scope(SERVER_STEP):
+            w = alive.astype(jnp.float32)
+            loss_c = jnp.where(alive, loss_c, 0.0)
+            n_alive = w.sum()
+            loss = (loss_c * w).sum() / jnp.maximum(n_alive, 1.0)
 
         def f(path, spec_t, srv, stack):
             if is_compressed(srv):
                 ba = n_stack_axes(spec_t, srv.codes)
-                codes_c, s_c, b_c = transport_encode_stacked(
-                    stack, srv.fmt, omc.pvt, ba
-                )
-                new_codes, s, b = kernel_ops.fused_aggregate(
-                    srv.codes, srv.s, srv.b, codes_c, s_c, b_c, w,
-                    sim.server_lr, srv.fmt, batch_axes=ba, pvt=omc.pvt,
-                )
+                with jax.named_scope(TRANSPORT_ENCODE):
+                    codes_c, s_c, b_c = transport_encode_stacked(
+                        stack, srv.fmt, omc.pvt, ba
+                    )
+                with jax.named_scope(SERVER_STEP):
+                    new_codes, s, b = kernel_ops.fused_aggregate(
+                        srv.codes, srv.s, srv.b, codes_c, s_c, b_c, w,
+                        sim.server_lr, srv.fmt, batch_axes=ba, pvt=omc.pvt,
+                    )
                 return CompressedVariable(new_codes, s, b, srv.fmt)
             # Unselected leaves keep the classic f32 mean + interpolation.
-            x = jnp.where(
-                alive.reshape((-1,) + (1,) * (stack.ndim - 1)), stack, 0.0
-            )
-            mean = cohort_lib.aggregate_weighted(x, w)
-            return srv + sim.server_lr * (mean - srv)
+            with jax.named_scope(SERVER_STEP):
+                x = jnp.where(
+                    alive.reshape((-1,) + (1,) * (stack.ndim - 1)), stack, 0.0
+                )
+                mean = cohort_lib.aggregate_weighted(x, w)
+                return srv + sim.server_lr * (mean - srv)
 
         new_storage = jax.tree_util.tree_map_with_path(
             f, specs, storage, stacked,
@@ -475,51 +489,57 @@ def make_round_fn(
         return new_storage, loss, n_alive, None
 
     def body(storage, ids_per_tier, batches_per_tier, alive, round_index, ef):
-        server_f32 = decompress_tree(storage)
-        models, losses, rows = [], [], []
-        for t, (one, ids_t) in enumerate(zip(ones, ids_per_tier)):
-            if batches_per_tier is None:
-                batches = jax.vmap(
-                    lambda c: jax.vmap(
-                        lambda s: data_fn(c, round_index, s)
-                    )(steps)
-                )(ids_t)
-            else:
-                batches = batches_per_tier[t]
-            if takes_ef:
-                rows_t = {k: v[ids_t] for k, v in ef.items()}
-                m, l, nr = _run_cohort(one, server_f32, batches, round_index,
-                                       ids_t, spec.client_chunk, rows_t)
-                rows.append(nr)
-            else:
-                m, l = _run_cohort(one, server_f32, batches, round_index,
-                                   ids_t, spec.client_chunk)
-            models.append(m)
-            losses.append(l)
-        stacked = jax.tree_util.tree_map(
-            lambda *xs: jnp.concatenate(xs, 0), *models
-        )
+        with jax.named_scope(DECOMPRESS):
+            server_f32 = decompress_tree(storage)
+        with jax.named_scope(CLIENT):
+            models, losses, rows = [], [], []
+            for t, (one, ids_t) in enumerate(zip(ones, ids_per_tier)):
+                if batches_per_tier is None:
+                    batches = jax.vmap(
+                        lambda c: jax.vmap(
+                            lambda s: data_fn(c, round_index, s)
+                        )(steps)
+                    )(ids_t)
+                else:
+                    batches = batches_per_tier[t]
+                if takes_ef:
+                    rows_t = {k: v[ids_t] for k, v in ef.items()}
+                    m, l, nr = _run_cohort(one, server_f32, batches,
+                                           round_index, ids_t,
+                                           spec.client_chunk, rows_t)
+                    rows.append(nr)
+                else:
+                    m, l = _run_cohort(one, server_f32, batches, round_index,
+                                       ids_t, spec.client_chunk)
+                models.append(m)
+                losses.append(l)
+            stacked = jax.tree_util.tree_map(
+                lambda *xs: jnp.concatenate(xs, 0), *models
+            )
+            loss_c = jnp.concatenate(losses)
         if fused_agg:
             new_storage, loss, n_alive, aux = finish_fused(
-                storage, stacked, jnp.concatenate(losses), alive
+                storage, stacked, loss_c, alive
             )
         else:
-            new_storage, loss, n_alive, aux = finish(
-                server_f32, stacked, jnp.concatenate(losses), alive
-            )
+            with jax.named_scope(SERVER_STEP):
+                new_storage, loss, n_alive, aux = finish(
+                    server_f32, stacked, loss_c, alive
+                )
         out: Tuple[Any, ...] = (new_storage, loss, n_alive)
         if takes_ef:
             # scatter the cohort's updated residual rows back into the
             # population state; dead clients keep their previous residual
             # (they never uploaded — the loop path skips them entirely)
-            ids_all = jnp.concatenate(list(ids_per_tier), 0)
-            new_ef = {}
-            for k, old in ef.items():
-                nr = jnp.concatenate([r[k] for r in rows], 0)
-                keep = alive.reshape((-1,) + (1,) * (nr.ndim - 1))
-                new_ef[k] = old.at[ids_all].set(
-                    jnp.where(keep, nr, old[ids_all])
-                )
+            with jax.named_scope(SERVER_STEP):
+                ids_all = jnp.concatenate(list(ids_per_tier), 0)
+                new_ef = {}
+                for k, old in ef.items():
+                    nr = jnp.concatenate([r[k] for r in rows], 0)
+                    keep = alive.reshape((-1,) + (1,) * (nr.ndim - 1))
+                    new_ef[k] = old.at[ids_all].set(
+                        jnp.where(keep, nr, old[ids_all])
+                    )
             out = out + (new_ef,)
         if collect_metrics:
             out = out + (aux,)
@@ -620,6 +640,11 @@ def run_round_vectorized(
     obs enabled the trained trees and ledgers stay bit/byte-identical to
     ``obs=None`` (tier-1 gated).  A cached ``round_fn`` must have been
     built with matching ``collect_metrics``.
+
+    The round is the span ``round`` (a profiler step numbered by
+    ``round_index``) holding ``round.sample`` (cohort and survival mask),
+    ``round.call`` (dispatch of the round program) and ``round.readback``
+    (the wait for its loss and alive count).
     """
     takes_ef = simulate.ef_lib.takes_residual(omc, strategy)
     collect = obs is not None and obs.collect_metrics
@@ -632,55 +657,60 @@ def run_round_vectorized(
             f"strategy {strategy.label!r} uses error feedback: pass the "
             f"ef= state (repro.compress.feedback.init_ef_state)"
         )
-    ids_per_tier = sample_tiered_cohort(key, spec, round_index)
-    alive = cohort_lib.survival_mask(key, spec.plan, round_index)
+    with null_span(obs, "round", step=int(round_index),
+                   round=int(round_index)):
+        with null_span(obs, "round.sample"):
+            ids_per_tier = sample_tiered_cohort(key, spec, round_index)
+            alive = cohort_lib.survival_mask(key, spec.plan, round_index)
 
-    args = [server_params, ids_per_tier]
-    if data_mode == "host":
-        args.append(_host_batches(data_fn, ids_per_tier, round_index,
-                                  sim.local_steps))
-    args += [alive, jnp.int32(round_index)]
-    with null_span(obs, "round", round=int(round_index)):
-        res = round_fn(*args, ef) if takes_ef else round_fn(*args)
-    base = 4 if takes_ef else 3
-    mean_model = res[base] if len(res) > base else None
-    if takes_ef:
-        new_storage, loss, n_alive, new_ef = res[:4]
-        for k in ef:
-            ef[k] = new_ef[k]
-    else:
-        new_storage, loss, n_alive = res[:3]
-
-    bundle = None
-    if collect:
-        # eager host-side bundle from the round's outputs (DESIGN.md §15):
-        # the compiled program is never asked to compute metric values, so
-        # enabling obs cannot perturb the trained tree
-        bundle = obs_metrics.server_round_bundle(
-            specs, server_params, new_storage, mean_model, sim.server_lr,
-        )
-        bundle["loss"] = loss
-        bundle["alive"] = n_alive
+        args = [server_params, ids_per_tier]
+        if data_mode == "host":
+            args.append(_host_batches(data_fn, ids_per_tier, round_index,
+                                      sim.local_steps))
+        args += [alive, jnp.int32(round_index)]
+        with null_span(obs, "round.call"):
+            res = round_fn(*args, ef) if takes_ef else round_fn(*args)
+        base = 4 if takes_ef else 3
+        mean_model = res[base] if len(res) > base else None
         if takes_ef:
-            ids_all = jnp.concatenate(
-                [jnp.asarray(i) for i in ids_per_tier], 0
-            )
-            bundle["ef_norm"] = obs_metrics.ef_rows_norm(
-                {k: v[ids_all] for k, v in ef.items()}
-            )
+            new_storage, loss, n_alive, new_ef = res[:4]
+            for k in ef:
+                ef[k] = new_ef[k]
+        else:
+            new_storage, loss, n_alive = res[:3]
 
-    n_alive = int(n_alive)
-    metrics: Dict[str, float] = dict(
-        loss=float(loss),
-        cohort=n_alive,
-        dropped=int(spec.plan.cohort_size - n_alive),
-    )
-    if wire_table is not None:
-        metrics.update(
-            round_wire_metrics(wire_table, omc, spec.tier_omcs(omc),
-                               ids_per_tier, alive, round_index,
-                               strategy=strategy)
+        bundle = None
+        if collect:
+            # eager host-side bundle from the round's outputs (DESIGN.md
+            # §15): the compiled program is never asked to compute metric
+            # values, so enabling obs cannot perturb the trained tree
+            bundle = obs_metrics.server_round_bundle(
+                specs, server_params, new_storage, mean_model, sim.server_lr,
+            )
+            bundle["loss"] = loss
+            bundle["alive"] = n_alive
+            if takes_ef:
+                ids_all = jnp.concatenate(
+                    [jnp.asarray(i) for i in ids_per_tier], 0
+                )
+                bundle["ef_norm"] = obs_metrics.ef_rows_norm(
+                    {k: v[ids_all] for k, v in ef.items()}
+                )
+
+        with null_span(obs, "round.readback"):
+            n_alive = int(n_alive)
+            loss = float(loss)
+        metrics: Dict[str, float] = dict(
+            loss=loss,
+            cohort=n_alive,
+            dropped=int(spec.plan.cohort_size - n_alive),
         )
+        if wire_table is not None:
+            metrics.update(
+                round_wire_metrics(wire_table, omc, spec.tier_omcs(omc),
+                                   ids_per_tier, alive, round_index,
+                                   strategy=strategy)
+            )
     if obs is not None:
         obs.record("round", bundle, round=int(round_index), **metrics)
     return new_storage, metrics

@@ -92,6 +92,11 @@ def pack_qparams(params, sinks=None):
     )
 
 
+#: Named scope of a leaf's materialization (decode of the codes, PVT
+#: affine, cast) in the programs that run a model (DESIGN.md §15).
+MATERIALIZE = "omc.materialize"
+
+
 class OMCMaterializer(Materializer):
     """Materializer that understands QParam / CompressedVariable leaves.
 
@@ -123,6 +128,10 @@ class OMCMaterializer(Materializer):
         return self._leaf(x, None)
 
     def _leaf(self, q, spec: Optional[ParamSpec]):
+        with jax.named_scope(MATERIALIZE):
+            return self._materialize(q, spec)
+
+    def _materialize(self, q, spec: Optional[ParamSpec]):
         if not isinstance(q, QParam):
             # plain leaf (e.g. fp32 baseline without sinks)
             if is_compressed(q):

@@ -184,6 +184,7 @@ def fused_aggregate(
             jax.ShapeDtypeStruct((sb, 4, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_aggregate",
     )(srv2, ss, sbias, cl2, cs, cb, w3, k)
 
     codes = new_codes.reshape(sb, m_pad)[:, :m].reshape(shape)

@@ -100,7 +100,7 @@ def _unpack_kernel(w_ref, o_ref, *, width: int):
 
 
 def _call(kernel, x, rows_in: int, rows_out: int, lanes: int, width: int,
-          interpret: bool) -> jax.Array:
+          interpret: bool, name: str) -> jax.Array:
     nblocks = x.shape[1]
     return pl.pallas_call(
         functools.partial(kernel, width=width),
@@ -109,6 +109,7 @@ def _call(kernel, x, rows_in: int, rows_out: int, lanes: int, width: int,
         out_specs=pl.BlockSpec((rows_out, lanes), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((rows_out, nblocks), jnp.uint32),
         interpret=interpret,
+        name=name,
     )(x)
 
 
@@ -125,7 +126,7 @@ def pack(codes: jax.Array, width: int, *, interpret: bool = False) -> jax.Array:
     nblocks, lanes = _blocks(n, p_f)
     flat = jnp.pad(flat, (0, nblocks * p_f - n))
     out = _call(_pack_kernel, flat.reshape(nblocks, p_f).T, p_f, p_w, lanes,
-                width, interpret)
+                width, interpret, "pack_bits")
     return out.T.reshape(-1)[: packed_words(n, width)]
 
 
@@ -139,7 +140,7 @@ def unpack(words: jax.Array, width: int, n: int, *, interpret: bool = False) -> 
     # Zero tail padding == the oracle's appended zero word.
     flat = jnp.pad(flat, (0, nblocks * p_w - flat.shape[0]))
     out = _call(_unpack_kernel, flat.reshape(nblocks, p_w).T, p_w, p_f, lanes,
-                width, interpret)
+                width, interpret, "unpack_bits")
     return out.T.reshape(-1)[:n]
 
 

@@ -100,5 +100,6 @@ def dequant_matmul(
             pltpu.VMEM((bm_, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="dequant_matmul",
     )(a, w_codes, s_arr, b_arr)
     return out[:m, :n]

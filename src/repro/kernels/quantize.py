@@ -87,6 +87,7 @@ def quantize(x: jax.Array, fmt: FloatFormat, *, interpret: bool = False) -> jax.
         out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), fmt.container_dtype),
         interpret=interpret,
+        name="quantize",
     )(x2)
     return out.reshape(-1)[:n].reshape(x.shape)
 
@@ -110,6 +111,7 @@ def dequantize(codes: jax.Array, fmt: FloatFormat, s=None, b=None,
         out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         interpret=interpret,
+        name="dequantize",
     )(c2, s_arr, b_arr)
     return out.reshape(-1)[:n].reshape(codes.shape)
 
@@ -136,5 +138,6 @@ def quantize_stats(x: jax.Array, fmt: FloatFormat, *, interpret: bool = False):
             jax.ShapeDtypeStruct((4, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="quantize_stats",
     )(x2)
     return codes.reshape(-1)[:n].reshape(x.shape), jnp.sum(sums, axis=1)

@@ -8,20 +8,23 @@ One handle, three layers:
     :class:`~repro.obs.metrics.MetricsSink`,
   * **tracing** (:mod:`repro.obs.trace`) — wall-clock spans (compile,
     dispatch, flush, hot-swap) plus virtual-clock spans for the async
-    engine's simulated timeline,
+    engine's simulated timeline; every span site also writes an
+    ``omc.<name>`` host event into the JAX profiler's trace while one is
+    recording (:func:`null_span`),
   * **export** (:mod:`repro.obs.export`) — JSONL event log +
     Chrome-trace/Perfetto JSON under ``experiments/obs/``, rendered by
     ``python -m repro.obs.report``.
 
 The contract every instrumented call site honors: ``obs=None`` (the
 default everywhere) must be a **true no-op** — no extra program outputs,
-no spans, no files — so the tier-1 bit-identity gates between paths are
-untouched; and with ``obs`` *enabled*, compiled round programs only
-expose values they already compute (the cohort mean) as extra outputs —
-all bundle math (update/quant-error/EF norms) runs **eagerly on the
-host** after the program returns, so the compiled round math is
-untouched and trained trees and wire ledgers stay bit/byte-identical
-(gated in tier-1).
+no ``Tracer`` spans, no files; only the profiler annotation, which
+records nothing unless a profiler is running — so the tier-1
+bit-identity gates between paths are untouched; and with ``obs``
+*enabled*, compiled round programs only expose values they already
+compute (the cohort mean) as extra outputs — all bundle math
+(update/quant-error/EF norms) runs **eagerly on the host** after the
+program returns, so the compiled round math is untouched and trained
+trees and wire ledgers stay bit/byte-identical (gated in tier-1).
 
 Typical use::
 
@@ -34,16 +37,14 @@ Typical use::
 from __future__ import annotations
 
 import os
-from contextlib import contextmanager
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, Optional
+
+import jax
 
 from repro.obs.metrics import Bundle, MetricsSink
-from repro.obs.trace import Span, Tracer, maybe_span
+from repro.obs.trace import Span, Tracer
 
-__all__ = [
-    "Obs", "MetricsSink", "Tracer", "Span", "Bundle",
-    "maybe_span", "null_span",
-]
+__all__ = ["Obs", "MetricsSink", "Tracer", "Span", "Bundle", "null_span"]
 
 DEFAULT_OUT_DIR = os.path.join("experiments", "obs")
 
@@ -73,10 +74,8 @@ class Obs:
                **fields: Any) -> Dict[str, Any]:
         return self.sink.record(kind, bundle, **fields)
 
-    @contextmanager
-    def span(self, name: str, **args: Any) -> Iterator[Dict[str, Any]]:
-        with maybe_span(self.tracer, name, **args) as a:
-            yield a
+    def span(self, name: str, **args: Any):
+        return null_span(self, name, **args)
 
     def vspan(self, name: str, ts: float, dur: float, **args: Any) -> None:
         if self.tracer is not None:
@@ -103,12 +102,38 @@ class Obs:
         )
 
 
-@contextmanager
-def null_span(obs: Optional[Obs], name: str,
-              **args: Any) -> Iterator[Dict[str, Any]]:
-    """``obs.span`` tolerant of ``obs=None`` — for instrumented call sites."""
-    if obs is None:
-        yield args
-    else:
-        with obs.span(name, **args) as a:
-            yield a
+class null_span:
+    """The one span helper of every instrumented call site, used as
+    ``with null_span(obs, name, **args) as args:``.
+
+    Always enters a profiler annotation ``omc.<name>`` (a
+    ``StepTraceAnnotation`` numbered ``step`` when given), which costs one
+    check and records nothing unless a profiler is running; with an
+    ``obs`` that traces, also records a wall span ``name`` on its
+    :class:`Tracer`.  ``as`` binds the mutable ``args`` dict.  A class,
+    not a generator: it sits on host loops of thousands of calls a second.
+    """
+
+    __slots__ = ("_note", "_span", "_args")
+
+    def __init__(self, obs: Optional[Obs], name: str, *,
+                 step: Optional[int] = None, **args: Any) -> None:
+        if step is None:
+            self._note = jax.profiler.TraceAnnotation("omc." + name)
+        else:
+            self._note = jax.profiler.StepTraceAnnotation("omc." + name,
+                                                          step_num=step)
+        self._span = (obs.tracer.span(name, **args)
+                      if obs is not None and obs.tracer is not None else None)
+        self._args = args
+
+    def __enter__(self) -> Dict[str, Any]:
+        self._note.__enter__()
+        return self._args if self._span is None else self._span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        try:
+            if self._span is not None:
+                self._span.__exit__(*exc)
+        finally:
+            self._note.__exit__(*exc)

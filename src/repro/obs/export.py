@@ -28,7 +28,8 @@ JSONL_KINDS = ("meta", "round", "flush", "serve", "span", "log")
 
 
 def span_record(span: Span) -> Dict[str, Any]:
-    """JSONL form of a span (kind=span; seconds, not µs)."""
+    """JSONL form of a span (kind=span; seconds, not µs; wall ``ts`` on
+    the profiler's clock; ``parent`` when the span had one)."""
     rec: Dict[str, Any] = {
         "kind": "span",
         "name": span.name,
@@ -36,6 +37,8 @@ def span_record(span: Span) -> Dict[str, Any]:
         "ts": span.ts,
         "dur": span.dur,
     }
+    if span.parent is not None:
+        rec["parent"] = span.parent
     if span.args:
         rec["args"] = _plain(span.args)
     return rec

@@ -65,6 +65,20 @@ def _histogram(values: List[float], bins: int = 8) -> List[str]:
     return lines
 
 
+def span_summary(spans: List[Dict[str, Any]]) -> Dict[str, Dict[str, float]]:
+    """Count, total and mean seconds of ``kind=span`` records, keyed
+    ``<cat>:<name>``."""
+    agg: Dict[str, Dict[str, float]] = {}
+    for s in spans:
+        key = f"{s.get('cat', 'wall')}:{s.get('name', '?')}"
+        rec = agg.setdefault(key, {"count": 0.0, "total_s": 0.0})
+        rec["count"] += 1
+        rec["total_s"] += float(s.get("dur", 0.0))
+    for rec in agg.values():
+        rec["mean_s"] = rec["total_s"] / rec["count"]
+    return agg
+
+
 def render(records: List[Dict[str, Any]], out=None) -> None:
     out = out if out is not None else sys.stdout
     by_kind: Dict[str, List[Dict[str, Any]]] = {}
@@ -133,17 +147,10 @@ def render(records: List[Dict[str, Any]], out=None) -> None:
     spans = by_kind.get("span", [])
     if spans:
         print(f"\n== spans ({len(spans)}) ==", file=out)
-        agg: Dict[str, Dict[str, float]] = {}
-        for s in spans:
-            key = f"{s.get('cat', 'wall')}:{s.get('name', '?')}"
-            rec = agg.setdefault(key, {"count": 0.0, "total_s": 0.0})
-            rec["count"] += 1
-            rec["total_s"] += float(s.get("dur", 0.0))
         rows = [
-            {"span": k, "count": int(v["count"]),
-             "total_s": v["total_s"],
-             "mean_ms": 1e3 * v["total_s"] / max(v["count"], 1.0)}
-            for k, v in sorted(agg.items())
+            {"span": k, "count": int(v["count"]), "total_s": v["total_s"],
+             "mean_ms": 1e3 * v["mean_s"]}
+            for k, v in sorted(span_summary(spans).items())
         ]
         _table(rows, ["span", "count", "total_s", "mean_ms"], out)
 

@@ -3,7 +3,10 @@
 Two clocks, one span type:
 
   * **wall** spans time host-side phases — compile, dispatch, flush,
-    hot-swap, payload encode/decode — with ``time.perf_counter``.
+    hot-swap, payload encode/decode — on the profiler's clock
+    (``time.time_ns``, what TraceMe stamps host events with on Linux), so
+    a recorded span lines up with its ``omc.*`` event in a profiler trace
+    (:func:`repro.obs.null_span` writes both).
   * **virtual** spans carry the async engine's simulated clock: a client
     round is a span at its check-in timestamp with the sampled latency as
     duration.  Virtual spans are *constructed*, never timed — the async
@@ -30,9 +33,11 @@ VIRTUAL = "virtual"
 class Span:
     """One closed interval on either clock.
 
-    ``ts``/``dur`` are **seconds** on the span's own clock: wall spans use
-    the tracer's epoch (first span at ~0), virtual spans use the async
-    engine's simulated time directly.
+    ``ts``/``dur`` are **seconds** on the span's own clock: wall spans are
+    absolute (``time.time_ns() / 1e9``, the profiler's clock), virtual
+    spans use the async engine's simulated time directly.  ``parent`` is
+    the name of the wall span open when this one started (the span that
+    caused it), ``None`` at top level and for virtual spans.
     """
 
     name: str
@@ -40,6 +45,7 @@ class Span:
     dur: float
     cat: str = WALL
     args: Dict[str, Any] = field(default_factory=dict)
+    parent: Optional[str] = None
 
     @property
     def end(self) -> float:
@@ -51,20 +57,16 @@ class Tracer:
 
     All recording funnels through :meth:`add`; :meth:`span` is the
     wall-clock context manager and :meth:`vspan` the virtual-clock
-    constructor.  ``tracer=None`` call sites use
-    :func:`maybe_span`, which degrades to a no-op.
+    constructor.  Call sites go through :func:`repro.obs.null_span`.
+    Open wall spans form a stack, whose top is the next span's parent.
     """
 
     def __init__(self) -> None:
         self._spans: List[Span] = []
-        self._epoch = time.perf_counter()
+        self._open: List[str] = []
 
     def __len__(self) -> int:
         return len(self._spans)
-
-    def now(self) -> float:
-        """Seconds since this tracer's epoch (wall clock)."""
-        return time.perf_counter() - self._epoch
 
     def add(self, span: Span) -> Span:
         self._spans.append(span)
@@ -77,11 +79,16 @@ class Tracer:
         Yields the mutable ``args`` dict so the body can attach results
         (e.g. byte counts) before the span closes.
         """
-        t0 = self.now()
+        parent = self._open[-1] if self._open else None
+        self._open.append(name)
+        t0 = time.time_ns()
         try:
             yield args
         finally:
-            self.add(Span(name=name, ts=t0, dur=self.now() - t0, args=args))
+            t1 = time.time_ns()
+            self._open.pop()
+            self.add(Span(name=name, ts=t0 / 1e9, dur=(t1 - t0) / 1e9,
+                          args=args, parent=parent))
 
     def vspan(self, name: str, ts: float, dur: float, **args: Any) -> Span:
         """Record a virtual-clock span at simulated time ``ts``."""
@@ -98,26 +105,3 @@ class Tracer:
         if name is not None:
             out = [s for s in out if s.name == name]
         return list(out)
-
-    def summary(self) -> Dict[str, Dict[str, float]]:
-        """Per-(cat, name) count/total/mean seconds — the benchmark view."""
-        agg: Dict[str, Dict[str, float]] = {}
-        for s in self._spans:
-            key = f"{s.cat}:{s.name}"
-            rec = agg.setdefault(key, {"count": 0.0, "total_s": 0.0})
-            rec["count"] += 1
-            rec["total_s"] += s.dur
-        for rec in agg.values():
-            rec["mean_s"] = rec["total_s"] / max(rec["count"], 1.0)
-        return agg
-
-
-@contextmanager
-def maybe_span(tracer: Optional[Tracer], name: str,
-               **args: Any) -> Iterator[Dict[str, Any]]:
-    """``tracer.span(...)`` when tracing, else a free no-op (§15 rule)."""
-    if tracer is None:
-        yield args
-    else:
-        with tracer.span(name, **args) as a:
-            yield a

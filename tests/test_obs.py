@@ -17,6 +17,7 @@ The contract under test:
 
 import io
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +39,7 @@ from repro.obs.export import (
     to_perfetto,
 )
 from repro.obs.log import Logger
-from repro.obs.trace import VIRTUAL, WALL, Span, Tracer, maybe_span
+from repro.obs.trace import VIRTUAL, WALL, Span, Tracer
 from repro.scale import ShardLayout, run_training_sharded
 
 CFG = cf.ConformerConfig(
@@ -159,15 +160,22 @@ def test_round_record_schema(tmp_path):
 
 def test_tracer_wall_spans_nest_and_order():
     tr = Tracer()
+    before = time.time_ns() / 1e9
     with tr.span("outer", idx=0) as args:
         with tr.span("inner"):
             pass
         args["bytes"] = 123
-    inner, outer = tr.spans()
+    with tr.span("next"):
+        pass
+    inner, outer, nxt = tr.spans()
     assert (inner.name, outer.name) == ("inner", "outer")
     assert outer.args == {"idx": 0, "bytes": 123}
     assert outer.ts <= inner.ts and inner.end <= outer.end + 1e-9
     assert all(s.cat == WALL for s in tr.spans())
+    # the caused-by parent, and the profiler's absolute clock
+    assert (inner.parent, outer.parent, nxt.parent) == ("outer", None, None)
+    assert before <= outer.ts <= nxt.end <= time.time_ns() / 1e9
+    assert span_record(inner)["parent"] == "outer"
 
 
 def test_tracer_virtual_vs_wall_under_fixed_trace(tmp_path):
@@ -190,7 +198,8 @@ def test_tracer_virtual_vs_wall_under_fixed_trace(tmp_path):
     assert len(w) == 2
     # the two clocks never mix categories
     assert not obs.tracer.spans(WALL, "client_round")
-    summary = obs.tracer.summary()
+    summary = obs_report.span_summary(
+        [span_record(s) for s in obs.tracer.spans()])
     assert summary["virtual:client_round"]["count"] == len(v)
     assert summary["virtual:client_round"]["mean_s"] == pytest.approx(2.0)
 
@@ -237,14 +246,19 @@ def test_export_roundtrip_schema(tmp_path):
 
 
 def test_null_span_and_maybe_span_are_noops():
+    """``null_span`` is the one helper (``maybe_span`` merged into it): no
+    ``Tracer`` record without an ``Obs`` or with tracing off, one with."""
     with null_span(None, "anything", a=1) as args:
         args["b"] = 2  # must accept writes like the live version
-    with maybe_span(None, "anything") as args:
+    assert args == {"a": 1, "b": 2}
+    with null_span(Obs(trace=False), "anything", step=3) as args:
         pass
-    tr = Tracer()
-    with maybe_span(tr, "live"):
-        pass
-    assert len(tr.spans()) == 1
+    obs = Obs()
+    with null_span(obs, "live", step=0):
+        with obs.span("child"):
+            pass
+    child, live = obs.tracer.spans()
+    assert (live.name, child.parent) == ("live", "live")
 
 
 def test_logger_quiet_and_structured(tmp_path):
