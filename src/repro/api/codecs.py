@@ -4,9 +4,10 @@ A *payload* is the serialized form of a storage pytree (the thing
 ``compress_tree`` / ``compress_params`` produce): ``CompressedVariable``
 leaves travel as their exact-width packed bitstream (11 bits/param for
 S1E3M7 — the paper's communication saving), everything else travels raw.
-The codec is host-side (numpy) and bit-exact: ``decode(encode(t)) == t``
-code-for-code, so wire transport composes with the storage-mode numerics
-without introducing a second rounding step.
+The codec frames the payload on the host and packs codes on the device; it
+is bit-exact: ``decode(encode(t)) == t`` code-for-code, so wire transport
+composes with the storage-mode numerics without introducing a second
+rounding step.
 
 Frame layout (little-endian, version 1)::
 
@@ -61,6 +62,7 @@ paper-table byte columns reconcile by construction
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import struct
 import zlib
@@ -71,7 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import packing
-from repro.core.formats import FloatFormat
+from repro.core.formats import FloatFormat, uint_container
 from repro.core.store import CompressedVariable, is_compressed
 from repro.obs import null_span
 
@@ -93,10 +95,12 @@ _CHUNK_FIELDS = 1 << 18
 
 # Host spans (DESIGN.md §15), ``omc.<name>`` in a profiler trace: one
 # ``codec.encode`` / ``codec.decode`` a payload; ``codec.d2h`` around every
-# read of a device array to the host; ``codec.pack`` / ``codec.unpack``
-# around each chunk's kernel call, which uploads the chunk as part of the
-# call (a separate upload first costs a host copy and the Python of
-# ``device_put`` for every chunk); ``codec.h2d`` around every other upload.
+# read of a device array to the host; ``codec.h2d`` around every upload;
+# ``codec.pack`` / ``codec.unpack`` around each chunk's kernel call.  A full
+# ``omc`` leaf crosses once each way: its codes are cut into chunks, packed
+# and joined on the device and its words read once (with s and b: three
+# reads); decode uploads the words once (with s and b: three uploads) and
+# unpacks and joins them on the device, reading nothing back.
 
 
 def _to_host(x, dtype=None) -> np.ndarray:
@@ -335,44 +339,57 @@ def _codes_np(cv: CompressedVariable) -> np.ndarray:
     return _to_host(cv.codes).reshape(-1)
 
 
-def _pack_chunk(codes: np.ndarray, bits: int) -> np.ndarray:
-    with null_span(None, "codec.pack"):  # uploads the chunk as it calls
-        words = packing.pack(codes, bits)
-    return _to_host(words, np.uint32)
+@functools.partial(jax.jit, static_argnums=1)
+def _split(x: jax.Array, size: int) -> List[jax.Array]:
+    """``x`` flattened and cut into ``size``-element pieces, the last one
+    ragged: one program per (shape, size), however many pieces."""
+    flat = x.reshape(-1)
+    return [flat[i:i + size] for i in range(0, flat.shape[0], size)]
 
 
-def _unpack_chunk(words: np.ndarray, bits: int, n: int) -> np.ndarray:
-    with null_span(None, "codec.unpack"):  # uploads the chunk as it calls
-        codes = packing.unpack(words, bits, n)
-    return _to_host(codes, np.uint32)
+_join = jax.jit(jnp.concatenate)  # one program per list of piece shapes
+
+# Unpacked chunks are cast to their container and joined this many at a time:
+# a program per group, not per chunk, and at most this many u32 chunks held.
+_GROUP = 16
 
 
-def _pack_np(codes_flat: np.ndarray, bits: int) -> np.ndarray:
-    n = codes_flat.size
-    if n <= _CHUNK_FIELDS:
-        return _pack_chunk(codes_flat, bits)
-    return np.concatenate([
-        _pack_chunk(codes_flat[i:i + _CHUNK_FIELDS], bits)
-        for i in range(0, n, _CHUNK_FIELDS)
-    ])
+@functools.partial(jax.jit, static_argnums=1)
+def _narrow_join(chunks: List[jax.Array], dtype) -> jax.Array:
+    return jnp.concatenate([c.astype(dtype) for c in chunks])
 
 
-def _unpack_np(words: np.ndarray, bits: int, n: int) -> np.ndarray:
-    if n <= _CHUNK_FIELDS:
-        return _unpack_chunk(words, bits, n)
+def _pack_np(codes: jax.Array, bits: int) -> np.ndarray:
+    """Device codes -> their packed u32 words on the host, read once."""
+    words = []
+    for piece in _split(codes, _CHUNK_FIELDS):
+        with null_span(None, "codec.pack"):
+            words.append(packing.pack(piece, bits))
+    return _to_host(words[0] if len(words) == 1 else _join(words), np.uint32)
+
+
+def _unpack_np(words: np.ndarray, bits: int, n: int) -> jax.Array:
+    """Host u32 words -> ``n`` flat codes on the device in the container of
+    ``bits``.  Chunks are cast by groups before the join, so the join holds
+    about twice the codes' container bytes, not their u32 form."""
+    dtype = uint_container(bits)
     step = _CHUNK_FIELDS * bits // 32  # words per full chunk
-    return np.concatenate([
-        _unpack_chunk(words[k * step:(k + 1) * step], bits,
-                      min(_CHUNK_FIELDS, n - i))
-        for k, i in enumerate(range(0, n, _CHUNK_FIELDS))
-    ])
+    pieces = _split(_to_device(words, jnp.uint32), step)
+    groups, chunks = [], []
+    for i, piece in zip(range(0, n, _CHUNK_FIELDS), pieces):
+        with null_span(None, "codec.unpack"):
+            chunks.append(packing.unpack(piece, bits, min(_CHUNK_FIELDS, n - i)))
+        if len(chunks) == _GROUP or i + _CHUNK_FIELDS >= n:
+            groups.append(_narrow_join(chunks, dtype))
+            chunks = []
+    del pieces  # the join holds only the codes
+    return groups[0] if len(groups) == 1 else _join(groups)
 
 
 def _encode_omc(cv: CompressedVariable, base) -> Tuple[Dict[str, Any], List[bytes]]:
     fmt = cv.fmt
     s = np.ascontiguousarray(_to_host(cv.s, np.float32))
     b = np.ascontiguousarray(_to_host(cv.b, np.float32))
-    codes = _codes_np(cv)
     meta = dict(
         kind="omc",
         fmt=fmt.name,
@@ -383,7 +400,7 @@ def _encode_omc(cv: CompressedVariable, base) -> Tuple[Dict[str, Any], List[byte
         sb_shape=list(np.shape(cv.s)),
         mode="full",
     )
-    full_words = _pack_np(codes, fmt.bits)
+    full_words = _pack_np(cv.codes, fmt.bits)
     chunks = [s.tobytes(), b.tobytes()]
     if (
         base is not None
@@ -391,14 +408,15 @@ def _encode_omc(cv: CompressedVariable, base) -> Tuple[Dict[str, Any], List[byte
         and base.fmt == fmt
         and tuple(base.codes.shape) == tuple(cv.codes.shape)
     ):
-        xor = codes.astype(np.uint32) ^ _codes_np(base).astype(np.uint32)
+        # the delta is found on the host: XOR against the base, np.nonzero
+        xor = _codes_np(cv).astype(np.uint32) ^ _codes_np(base).astype(np.uint32)
         (idx,) = np.nonzero(xor)
         delta_bytes = 4 * idx.size + 4 * packing.packed_words(max(idx.size, 1), fmt.bits)
         if idx.size and delta_bytes < 4 * full_words.size:
             meta["mode"] = "delta"
             meta["nnz"] = int(idx.size)
             chunks.append(np.ascontiguousarray(idx.astype(np.uint32)).tobytes())
-            chunks.append(_pack_np(xor[idx], fmt.bits).tobytes())
+            chunks.append(_pack_np(_to_device(xor[idx]), fmt.bits).tobytes())
             return meta, chunks
         if idx.size == 0:
             meta["mode"] = "delta"
@@ -463,15 +481,17 @@ def _decode_omc(meta: Dict[str, Any], body: memoryview, off: int, base):
             nwords = packing.packed_words(nnz, fmt.bits)
             words = np.frombuffer(body, np.uint32, nwords, off)
             off += 4 * nwords
-            xor = _unpack_np(words, fmt.bits, nnz)
-            codes[idx] ^= xor
+            codes[idx] ^= _to_host(_unpack_np(words, fmt.bits, nnz))
+        codes = codes.astype(np.dtype(fmt.container_dtype))
     else:
         nwords = packing.packed_words(n, fmt.bits)
         words = np.frombuffer(body, np.uint32, nwords, off)
         off += 4 * nwords
         codes = _unpack_np(words, fmt.bits, n)
+    if not isinstance(codes, jax.Array):
+        codes = _to_device(codes)
     cv = CompressedVariable(
-        _to_device(codes.reshape(shape).astype(np.dtype(fmt.container_dtype))),
+        codes.reshape(shape).astype(fmt.container_dtype),
         _to_device(s.reshape(sb_shape), jnp.float32),
         _to_device(b.reshape(sb_shape), jnp.float32),
         fmt,

@@ -43,6 +43,15 @@ import numpy as np
 _FMT_RE = re.compile(r"^S1E(\d+)M(\d+)$")
 
 
+def uint_container(bits: int):
+    """The narrowest uint dtype that holds a ``bits``-wide code."""
+    if bits <= 8:
+        return jnp.uint8
+    if bits <= 16:
+        return jnp.uint16
+    return jnp.uint32
+
+
 @dataclasses.dataclass(frozen=True)
 class FloatFormat:
     """A 1-sign / `exp_bits`-exponent / `mant_bits`-mantissa float format."""
@@ -99,11 +108,7 @@ class FloatFormat:
 
     @property
     def container_dtype(self):
-        if self.bits <= 8:
-            return jnp.uint8
-        if self.bits <= 16:
-            return jnp.uint16
-        return jnp.uint32
+        return uint_container(self.bits)
 
     @property
     def container_bytes_per_value(self) -> int:

@@ -1,5 +1,7 @@
 """Wire-format codec + session tests (repro.api, DESIGN.md §7)."""
 
+import logging
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,10 +11,12 @@ from repro.api import codecs
 from repro.api.session import FLClient, FLSession, ServeSession
 from repro.core.omc import OMCConfig
 from repro.core.policy import QuantizePolicy
-from repro.core.store import compress_tree, is_compressed
+from repro.core.store import (CompressedVariable, compress_tree,
+                              compress_variable, is_compressed)
 from repro.data.synthetic import make_lm_task
 from repro.federated.cohort import CohortPlan
 from repro.federated.state import state_bytes_report
+from repro.kernels import ref
 from repro.models import transformer as tr
 from repro.models.common import IDENTITY_MAT
 
@@ -82,6 +86,116 @@ def test_chunked_pack_is_byte_identical(fmt, monkeypatch):
     assert codecs.encode_payload(ct2, base=ct) == whole_delta
     assert_trees_bit_equal(ct, codecs.decode_payload(whole)[0])
     assert_trees_bit_equal(ct2, codecs.decode_payload(whole_delta, base=ct)[0])
+
+
+CHUNK = 96  # fields per device call in the tests below
+
+
+def _leaf(fmt: str, shape, per_layer: bool, seed=0):
+    """A compressed leaf with 0-d or per-layer (leading axis) s and b."""
+    x = jax.random.normal(jax.random.PRNGKey(seed), shape)
+    return compress_variable(x, OMCConfig.parse(fmt).fmt, batch_axes=int(per_layer))
+
+
+def _oracle_words(codes, bits: int) -> np.ndarray:
+    """The canonical stream as the host-side codec built it: the jnp oracle
+    packs each ``CHUNK``-field slice of a numpy copy, words joined."""
+    flat = np.asarray(codes).reshape(-1)
+    return np.concatenate([np.asarray(ref.ref_pack(flat[i:i + CHUNK], bits), np.uint32)
+                           for i in range(0, flat.size, CHUNK)])
+
+
+def _body(payload: bytes) -> bytes:
+    return payload[codecs.peek_payload(payload).header_bytes:]
+
+
+def _sb_bytes(cv) -> bytes:
+    return b"".join(np.ascontiguousarray(np.asarray(a, np.float32)).tobytes()
+                    for a in (cv.s, cv.b))
+
+
+@pytest.mark.parametrize("per_layer", [False, True], ids=["scalar_sb", "per_layer_sb"])
+@pytest.mark.parametrize("fmt,shape", [
+    ("S1E3M7", (3, 20)),    # under one chunk
+    ("S1E3M7", (3, 32)),    # exactly one chunk
+    ("S1E3M7", (3, 96)),    # three whole chunks
+    ("S1E3M7", (3, 100)),   # three chunks and a ragged 12
+    ("S1E3M7", (3, 1000)),  # 32 chunks: two cast groups, the last chunk ragged
+    ("S1E2M3", (3, 100)),   # u8 container
+    ("S1E4M14", (3, 100)),  # u32 container
+], ids=["under", "one", "whole", "ragged", "groups", "ragged_u8", "ragged_u32"])
+def test_full_payload_matches_host_oracle(fmt, shape, per_layer, monkeypatch):
+    """A full leaf packed on the device gives the host codec's bytes, and
+    decodes to device codes of the container dtype."""
+    monkeypatch.setattr(codecs, "_CHUNK_FIELDS", CHUNK)
+    cv = _leaf(fmt, shape, per_layer)
+    assert np.size(cv.s) == (3 if per_layer else 1)
+    payload = codecs.encode_payload(dict(w=cv))
+    assert _body(payload) == _sb_bytes(cv) + _oracle_words(cv.codes, cv.fmt.bits).tobytes()
+    back = codecs.decode_payload(payload)[0]["w"]
+    assert isinstance(back.codes, jax.Array)
+    assert back.codes.dtype == cv.fmt.container_dtype
+    assert_trees_bit_equal(dict(w=cv), dict(w=back))
+
+
+def test_delta_payload_matches_host_oracle(monkeypatch):
+    """A delta leaf is still found and packed from host codes: sorted
+    indices, then the XOR of the changed codes packed chunk by chunk."""
+    monkeypatch.setattr(codecs, "_CHUNK_FIELDS", CHUNK)
+    base = _leaf("S1E3M7", (3, 1000), per_layer=True)
+    codes = np.asarray(base.codes).copy()
+    changed = np.arange(0, 3000, 15)  # 200 codes: three chunks, the last ragged
+    codes.reshape(-1)[changed] ^= 0x55
+    new = CompressedVariable(jnp.asarray(codes), base.s, base.b, base.fmt)
+    payload = codecs.encode_payload(dict(w=new), base=dict(w=base))
+    xor = (codes.reshape(-1) ^ np.asarray(base.codes).reshape(-1)).astype(np.uint32)
+    assert _body(payload) == (_sb_bytes(new) + changed.astype(np.uint32).tobytes()
+                              + _oracle_words(xor[changed], new.fmt.bits).tobytes())
+    back, info = codecs.decode_payload(payload, base=dict(w=base))
+    assert info.is_delta
+    assert_trees_bit_equal(dict(w=new), back)
+
+
+def test_decode_unpacks_through_unpack_np(monkeypatch):
+    """A full leaf's codes come from ``_unpack_np``, which may hand back a
+    host array: one flipped field there is one changed decoded code."""
+    cv = _leaf("S1E3M7", (3, 100), per_layer=False)
+    real = codecs._unpack_np
+
+    def flipped(words, bits, n):
+        out = np.array(real(words, bits, n))
+        out[0] ^= 1
+        return out
+
+    monkeypatch.setattr(codecs, "_unpack_np", flipped)
+    back = codecs.decode_payload(codecs.encode_payload(dict(w=cv)))[0]["w"]
+    diff = np.asarray(back.codes) != np.asarray(cv.codes)
+    assert diff.sum() == 1 and diff.reshape(-1)[0]
+
+
+def _compiles(caplog, fn) -> int:
+    """Programs JAX traces and compiles while ``fn`` runs."""
+    caplog.clear()
+    with jax.log_compiles(), caplog.at_level(logging.WARNING):
+        jax.block_until_ready(fn())
+    return sum(r.getMessage().startswith("Compiling ") for r in caplog.records)
+
+
+def test_codec_programs_do_not_grow_with_chunks(monkeypatch, caplog):
+    """A round trip compiles a fixed set of programs for a leaf shape,
+    however many chunks the leaf has, and a second one compiles nothing."""
+    monkeypatch.setattr(codecs, "_CHUNK_FIELDS", CHUNK)
+    counts = []
+    for chunks in (20, 40):  # whole chunks, then a ragged 40: two, three groups
+        tree = dict(w=_leaf("S1E3M7", (chunks * CHUNK + 40,), per_layer=False))
+        jax.clear_caches()
+
+        def trip():
+            return codecs.decode_payload(codecs.encode_payload(tree))[0]
+
+        counts.append(_compiles(caplog, trip))
+        assert _compiles(caplog, trip) == 0
+    assert counts[0] == counts[1] > 0
 
 
 def test_encode_and_digest_release_leaves_without_gc():
