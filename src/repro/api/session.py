@@ -512,7 +512,10 @@ class ServeSession:
 
     Wraps ``make_serve_fns``: prefill/decode are jitted once; ``hot_swap``
     replaces the storage tree from a wire payload between rounds without
-    touching the compiled functions (same treedef/shapes/dtypes).
+    touching the compiled functions (same treedef/shapes/dtypes).  Both
+    consume the cache they are given (donated): its buffers become the
+    returned cache's, so one cache exists at a time, and the old one must
+    not be used again.
     """
 
     def __init__(self, family, cfg, storage, compute_dtype=jnp.float32,
@@ -522,8 +525,10 @@ class ServeSession:
         self.storage = storage
         self.obs = obs
         prefill_fn, decode_fn = make_serve_fns(family, cfg, compute_dtype)
-        self._prefill = jax.jit(prefill_fn)
-        self._decode = jax.jit(decode_fn)
+        # keep_unused: a prefill that only reads its cache's shape still
+        # receives, and so frees, the buffers it was given
+        self._prefill = jax.jit(prefill_fn, donate_argnums=2, keep_unused=True)
+        self._decode = jax.jit(decode_fn, donate_argnums=1, keep_unused=True)
         self.swaps = 0
         self.queries = 0
         # per-swap wall ms (decode payload + materialize the new storage) —

@@ -74,7 +74,7 @@ def _mask_bias(q_pos, k_pos, *, causal: bool, window: Optional[int], k_valid=Non
 def attend(
     q: jax.Array,  # [B, Sq, H, hd]
     k: jax.Array,  # [B, Sk, KVH, hd]
-    v: jax.Array,  # [B, Sk, KVH, hd]
+    v: jax.Array,  # [B, Sk, KVH, hd_v]
     q_pos: jax.Array,  # [B, Sq] int32 absolute positions
     k_pos: jax.Array,  # [B, Sk]
     *,
@@ -83,8 +83,13 @@ def attend(
     k_valid: Optional[jax.Array] = None,  # [B, Sk] bool
     q_block: int = 512,
     kv_block: int = 512,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """Online-softmax chunked attention. Returns [B, Sq, H, hd] (q dtype).
+    """Online-softmax chunked attention. Returns [B, Sq, H, hd_v] (q dtype).
+
+    Scores are scaled by ``scale``, ``1/sqrt(hd)`` by default; the value head
+    size ``hd_v`` may differ from the query/key size ``hd`` (latent attention:
+    192 against 128).
 
     Layout: the q-block index is a *tensor dimension* sharded over the model
     axis (Ulysses-style sequence parallelism) — q blocks are independent given
@@ -97,8 +102,10 @@ def attend(
     """
     b, sq, h, hd = q.shape
     _, sk, kvh, _ = k.shape
+    hd_v = v.shape[-1]
     g = h // kvh
-    scale = 1.0 / np.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
 
     # Pin head/hd dims replicated: the 4-D reshape from the tensor-sharded
     # projection otherwise lets GSPMD shard head_dim, which turns the score
@@ -125,7 +132,7 @@ def attend(
     # [nk, B, kv_block, ...] scan layouts — each block is replicated over the
     # model axis while it streams past every (sharded) q block.
     k_js = kf.reshape(b, nk, kv_block, kvh, hd).transpose(1, 0, 2, 3, 4)
-    v_js = vf.reshape(b, nk, kv_block, kvh, hd).transpose(1, 0, 2, 3, 4)
+    v_js = vf.reshape(b, nk, kv_block, kvh, hd_v).transpose(1, 0, 2, 3, 4)
     kp_js = k_pos.reshape(b, nk, kv_block).transpose(1, 0, 2)
     kv_js = k_valid.reshape(b, nk, kv_block).transpose(1, 0, 2)
 
@@ -153,12 +160,12 @@ def attend(
     m0 = jnp.full((b, nq, q_block, kvh, g), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, nq, q_block, kvh, g), jnp.float32)
     a0 = shard_hint(
-        jnp.zeros((b, nq, q_block, kvh, g, hd), jnp.float32),
+        jnp.zeros((b, nq, q_block, kvh, g, hd_v), jnp.float32),
         "batch", "qblk", None, None, None, None,
     )
     (m, l, acc), _ = jax.lax.scan(kv_chunk, (m0, l0, a0), (k_js, v_js, kp_js, kv_js))
     out = acc / jnp.maximum(l[..., None], 1e-30)
-    out = out.reshape(b, sq, h, hd)
+    out = out.reshape(b, sq, h, hd_v)
     return out.astype(q.dtype)
 
 
@@ -201,6 +208,35 @@ def init_cache(
     )
 
 
+class LatentCache(NamedTuple):
+    """Per-layer-stacked cache of multi-head latent attention (MLA).
+
+    c: [L, B, rank, S_buf], each position's normalised latent, shared by all
+    heads; k_pe: [L, B, rope_dim, S_buf], its rotated key part (one head,
+    read by all).  Slots ``0 .. length-1`` hold positions ``0 .. length-1``.
+    Positions are the minor axis, the layout XLA gives the prefill's
+    per-layer latents on the TPU, so writing them needs no relayout copy of
+    the cache.
+    """
+
+    c: jax.Array
+    k_pe: jax.Array
+    length: jax.Array  # [] int32
+
+    @property
+    def buf_len(self) -> int:
+        return self.c.shape[3]
+
+
+def init_latent_cache(n_layers: int, batch: int, buf_len: int, rank: int,
+                      rope_dim: int, dtype=jnp.float32) -> LatentCache:
+    return LatentCache(
+        c=jnp.zeros((n_layers, batch, rank, buf_len), dtype),
+        k_pe=jnp.zeros((n_layers, batch, rope_dim, buf_len), dtype),
+        length=jnp.zeros((), jnp.int32),
+    )
+
+
 def cache_shard_hint(c: KVCache) -> KVCache:
     """Sharding: batch->data; KV heads->tensor when divisible else seq->model."""
     return KVCache(
@@ -229,6 +265,23 @@ def cache_insert(layer_k, layer_v, layer_pos, k_new, v_new, position, ring: bool
     return k, v, pos
 
 
+def cache_write(c: KVCache, k_new, v_new, position, ring: bool) -> KVCache:
+    """Write one token's K/V of every layer (``k_new``/``v_new`` [L, B, 1,
+    KVH, hd]) at absolute ``position``, in the slot ``cache_insert`` picks,
+    with one update of each stacked array: after a decode step's layers have
+    read the cache, so a donated cache changes in place.  The slot a ring
+    buffer overwrites holds a position the window already masks."""
+    s_buf = c.k.shape[2]
+    slot = jnp.where(ring, position % s_buf, jnp.minimum(position, s_buf - 1))
+    at = (0, 0, slot, 0, 0)
+    return c._replace(
+        k=jax.lax.dynamic_update_slice(c.k, k_new.astype(c.k.dtype), at),
+        v=jax.lax.dynamic_update_slice(c.v, v_new.astype(c.v.dtype), at),
+        pos=jax.lax.dynamic_update_slice(
+            c.pos, jnp.full(c.pos.shape[:2] + (1,), position, jnp.int32), at[:3]),
+    )
+
+
 def decode_attend(
     q: jax.Array,  # [B, 1, H, hd]
     layer_k: jax.Array,  # [B, S_buf, KVH, hd]
@@ -238,8 +291,12 @@ def decode_attend(
     *,
     window: Optional[int] = None,
     causal: bool = True,  # False for cross-attention memory
+    k_new: Optional[jax.Array] = None,  # [B, 1, KVH, hd]: the query's own
+    v_new: Optional[jax.Array] = None,  # key/value, attended beside the cache
 ) -> jax.Array:
-    """Single-token attention against the cache (no chunking needed: Sq=1)."""
+    """Single-token attention against the cache (no chunking needed: Sq=1).
+    With ``k_new``/``v_new`` the query also attends its own position, which
+    the cache does not hold yet (``cache_write`` adds it after the layers)."""
     b, _, h, hd = q.shape
     kvh = layer_k.shape[2]
     g = h // kvh
@@ -250,6 +307,13 @@ def decode_attend(
     valid = layer_pos >= 0
     bias = _mask_bias(q_pos, layer_pos, causal=causal, window=window, k_valid=valid)
     s = s + bias[:, :, None, None, :]
+    if k_new is not None:
+        s = jnp.concatenate(
+            [s, jnp.einsum("bqhgd,bkhd->bqhgk", qg, k_new.astype(jnp.float32))], -1)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bqhgk,bkhd->bqhgd", p, layer_v.astype(jnp.float32))
+    if k_new is not None:
+        out = (jnp.einsum("bqhgk,bkhd->bqhgd", p[..., :-1], layer_v.astype(jnp.float32))
+               + jnp.einsum("bqhgk,bkhd->bqhgd", p[..., -1:], v_new.astype(jnp.float32)))
+    else:
+        out = jnp.einsum("bqhgk,bkhd->bqhgd", p, layer_v.astype(jnp.float32))
     return out.reshape(b, 1, h, hd).astype(q.dtype)

@@ -21,6 +21,7 @@ Design notes
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -282,6 +283,48 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0):
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-temperature factor ``0.1·mscale·ln(factor) + 1``
+    (1 for no scaling)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, original_max: int,
+                          beta_fast: float, beta_slow: float) -> Tuple[int, int]:
+    """Rotary pair indices between which YaRN ramps from extrapolated to
+    interpolated frequencies: ``floor``/``ceil`` of the index that turns
+    ``beta`` times over ``original_max`` positions, clamped to [0, dim-1]."""
+
+    def index(rotations):
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    return max(math.floor(index(beta_fast)), 0), min(math.ceil(index(beta_slow)), dim - 1)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies [dim/2] (arXiv 2309.00071, DeepSeek-V2's
+    rotary): ``θ^(-2i/dim)`` below the correction range, that over ``factor``
+    above it, and a linear ramp between."""
+    freq_extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    freq_inter = freq_extra / factor
+    low, high = yarn_correction_range(dim, theta, original_max, beta_fast, beta_slow)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (freq_inter * ramp + freq_extra * (1.0 - ramp)).astype(np.float32)
+
+
+def apply_rope_interleaved(x: jax.Array, positions: jax.Array, inv_freq) -> jax.Array:
+    """Rotary on adjacent pairs ``(x[2i], x[2i+1])`` (DeepSeek's layout): the
+    result holds the rotated even members, then the rotated odd ones.
+    x: [..., S, H, d]; positions: [..., S]; inv_freq: [d/2]."""
+    ang = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq, jnp.float32)
+    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    even, odd = x[..., 0::2], x[..., 1::2]
+    return jnp.concatenate(
+        [even * cos - odd * sin, odd * cos + even * sin], axis=-1
     ).astype(x.dtype)
 
 

@@ -46,7 +46,8 @@ from .common import (
     stack_layer_params,
     wspec,
 )
-from .transformer import TransformerConfig, _embed_lookup, _qkv, param_specs as _dense_param_specs
+from .transformer import (TransformerConfig, _embed_lookup, _head_weight, _qkv,
+                          param_specs as _dense_param_specs)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -275,6 +276,59 @@ def _capacity(tokens: int, cfg: MoEConfig) -> int:
 
 
 # ---------------------------------------------------------------------------
+# Held-expert layer: route over every expert, compute the ones held here,
+# drop no token (DeepSeek-style serving MoE; DESIGN.md §16)
+# ---------------------------------------------------------------------------
+
+
+def route_topk(x2d: jax.Array, router_w: jax.Array, top_k: int, *,
+               normalize: bool, scaling: float = 1.0):
+    """[T, D] -> (gate weights [T, k], expert ids [T, k]).
+
+    Softmax over all the router's experts in float32 (the router matmul at
+    full precision), greedy top-k; the k weights are renormalised to sum to 1
+    only with ``normalize``, then multiplied by ``scaling``."""
+    logits = jnp.dot(x2d.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gate, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if normalize:
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-20)
+    return gate * scaling, ids
+
+
+def held_expert_ffn(x2d: jax.Array, gate: jax.Array, ids: jax.Array,
+                    w1: jax.Array, w3: jax.Array, w2: jax.Array,
+                    held, n_experts: int) -> jax.Array:
+    """This device's part of the routed experts' output: for every token,
+    ``Σ gate_e · SwiGLU_e(x)`` over its routed experts ``e`` that are held
+    here; experts held elsewhere add nothing.
+
+    x2d [T, D]; gate/ids [T, k] over all ``n_experts``; w1/w3 [H, D, F],
+    w2 [H, F, D] for the ``held`` global expert ids, in that order.  No
+    capacity and no drop: the T·k (token, expert) pairs are sorted by held
+    expert (pairs of experts held elsewhere last) and run as one grouped
+    matmul per weight (``lax.ragged_dot``), whose groups are the held
+    experts' pair counts."""
+    t, d = x2d.shape
+    k = ids.shape[1]
+    n_held = len(held)
+    local = np.full(n_experts, n_held, np.int32)  # global id -> slot; n_held: not here
+    local[np.asarray(held)] = np.arange(n_held, dtype=np.int32)
+    slot = jnp.asarray(local)[ids].reshape(-1)  # [T*k]
+    order = jnp.argsort(slot, stable=True)
+    sizes = jnp.zeros((n_held + 1,), jnp.int32).at[slot].add(1)[:n_held]
+    xs = x2d[order // k]
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=jnp.float32))
+    h = h * jax.lax.ragged_dot(xs, w3, sizes, preferred_element_type=jnp.float32)
+    out = jax.lax.ragged_dot(h, w2, sizes, preferred_element_type=jnp.float32)
+    # rows past the held experts' groups belong to no group: zero them
+    out = jnp.where((slot[order] < n_held)[:, None], out, 0.0)
+    out = out[jnp.argsort(order)].reshape(t, k, d)  # back to (token, choice)
+    weight = jnp.where(slot.reshape(t, k) < n_held, gate, 0.0)
+    return (weight[..., None] * out).sum(1)
+
+
+# ---------------------------------------------------------------------------
 # forward / loss / serve
 # ---------------------------------------------------------------------------
 
@@ -313,13 +367,8 @@ def forward(cfg: MoEConfig, params, batch, mat: Materializer):
 
 def loss(cfg: MoEConfig, params, batch, mat: Materializer) -> jax.Array:
     hidden, aux = forward(cfg, params, batch, mat)
-    head = (
-        mat({"h": params["lm_head"]}, {"h": wspec("fsdp", "tensor")})["h"]
-        if not cfg.tie_embeddings
-        else mat({"e": params["embed"]},
-                 {"e": ParamSpec(("fsdp", "tensor"), ("tensor", None))})["e"].T
-    )
-    ce = softmax_xent_chunked(hidden, head, batch["labels"], batch.get("mask"))
+    ce = softmax_xent_chunked(hidden, _head_weight(cfg, params, mat), batch["labels"],
+                              batch.get("mask"))
     return ce + aux / cfg.n_layers
 
 
@@ -372,11 +421,7 @@ def prefill(cfg: MoEConfig, params, batch, mat: Materializer, cache):
         attn.KVCache(k=ks, v=vs, pos=ps, length=jnp.asarray(s, jnp.int32))
     )
     x = rms_norm(x, mat.leaf(params["final_norm"]), cfg.norm_eps)
-    head = (
-        mat({"h": params["lm_head"]}, {"h": wspec("fsdp", "tensor")})["h"]
-        if not cfg.tie_embeddings else None
-    )
-    logits = x[:, -1:] @ head
+    logits = x[:, -1:] @ _head_weight(cfg, params, mat)
     return new_cache, shard_hint(logits, "batch", None, "tensor")
 
 
@@ -392,7 +437,7 @@ def decode_step(cfg: MoEConfig, params, cache, tokens, mat: Materializer):
     specs = block_specs(cfg)
     ring = cfg.window is not None
 
-    def body(carry, xs):
+    def body(carry, xs):  # the cache read only, written after (transformer.decode_step)
         x_, aux = carry
         w_layer, (kc, vc, pc) = xs
         w = mat(w_layer, specs)
@@ -400,21 +445,18 @@ def decode_step(cfg: MoEConfig, params, cache, tokens, mat: Materializer):
         q, k, v = _qkv(w, h, cfg)
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-        kc, vc, pc = attn.cache_insert(kc, vc, pc, k, v, position, ring=ring)
-        o = attn.decode_attend(q, kc, vc, pc, position, window=cfg.window)
+        o = attn.decode_attend(q, kc, vc, pc, position, window=cfg.window, k_new=k, v_new=v)
         o = o.reshape(b, 1, cfg.q_dim)
         x_ = x_ + shard_hint(o @ w["wo"], "batch", None, None)
         h = rms_norm(x_, w["mlp_norm"], cfg.norm_eps)
         y, aux_l = moe_ffn(h, w, cfg)
-        return (x_ + y, aux + aux_l), (kc, vc, pc)
+        return (x_ + y, aux + aux_l), (k, v)
 
-    (x, _aux), (ks, vs, ps) = jax.lax.scan(
+    (x, _aux), (ks, vs) = jax.lax.scan(
         body, (x, jnp.float32(0.0)), (params["blocks"], (cache.k, cache.v, cache.pos))
     )
-    new_cache = attn.cache_shard_hint(
-        attn.KVCache(k=ks, v=vs, pos=ps, length=cache.length + 1)
-    )
+    c = attn.cache_write(cache, ks, vs, position, ring)
+    new_cache = attn.cache_shard_hint(c._replace(length=cache.length + 1))
     x = rms_norm(x, mat.leaf(params["final_norm"]), cfg.norm_eps)
-    head = mat({"h": params["lm_head"]}, {"h": wspec("fsdp", "tensor")})["h"]
-    logits = x @ head
+    logits = x @ _head_weight(cfg, params, mat)
     return new_cache, shard_hint(logits, "batch", None, "tensor")

@@ -19,19 +19,20 @@ from __future__ import annotations
 from types import ModuleType
 from typing import Dict
 
-from . import conformer, encdec, griffin, moe, transformer, xlstm
+from . import conformer, deepseek_v2, encdec, griffin, moe, transformer, xlstm
 
 _FAMILIES: Dict[str, ModuleType] = {
     "transformer": transformer,
     "vlm": transformer,  # prefix_embeds > 0 in the config
     "moe": moe,
+    "deepseek_v2": deepseek_v2,
     "xlstm": xlstm,
     "griffin": griffin,
     "encdec": encdec,
     "conformer": conformer,
 }
 
-SERVABLE = {"transformer", "vlm", "moe", "xlstm", "griffin", "encdec"}
+SERVABLE = {"transformer", "vlm", "moe", "deepseek_v2", "xlstm", "griffin", "encdec"}
 
 
 def get_family(name: str) -> ModuleType:

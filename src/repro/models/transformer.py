@@ -348,6 +348,9 @@ def decode_step(cfg: TransformerConfig, params, cache: attn.KVCache,
     specs = block_specs(cfg)
     ring = cfg.window is not None
 
+    # the layers only read the cache; each attends its new token beside it,
+    # and the tokens are written once after the last layer, so a donated
+    # cache changes in place and no layer's slice is copied
     def body(x_, xs):
         w_layer, (kc, vc, pc) = xs
         w = mat(w_layer, specs)
@@ -355,18 +358,16 @@ def decode_step(cfg: TransformerConfig, params, cache: attn.KVCache,
         q, k, v = _qkv(w, h, cfg)
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-        kc, vc, pc = attn.cache_insert(kc, vc, pc, k, v, position, ring=ring)
-        o = attn.decode_attend(q, kc, vc, pc, position, window=cfg.window)
+        o = attn.decode_attend(q, kc, vc, pc, position, window=cfg.window, k_new=k, v_new=v)
         o = o.reshape(b, 1, cfg.q_dim)
         x_ = x_ + shard_hint(o @ w["wo"], "batch", None, None)
         h = rms_norm(x_, w["mlp_norm"], cfg.norm_eps)
         x_ = x_ + swiglu(h, w["w1"], w["w3"], w["w2"])
-        return x_, (kc, vc, pc)
+        return x_, (k, v)
 
-    x, (ks, vs, ps) = jax.lax.scan(body, x, (params["blocks"], (cache.k, cache.v, cache.pos)))
-    new_cache = attn.cache_shard_hint(
-        attn.KVCache(k=ks, v=vs, pos=ps, length=cache.length + 1)
-    )
+    x, (ks, vs) = jax.lax.scan(body, x, (params["blocks"], (cache.k, cache.v, cache.pos)))
+    c = attn.cache_write(cache, ks, vs, position, ring)
+    new_cache = attn.cache_shard_hint(c._replace(length=cache.length + 1))
     x = rms_norm(x, mat.leaf(params["final_norm"]), cfg.norm_eps)
     logits = x @ _head_weight(cfg, params, mat)
     return new_cache, shard_hint(logits, "batch", None, "tensor")

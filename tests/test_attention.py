@@ -84,3 +84,61 @@ def test_pick_q_block_divisibility():
     assert _pick_q_block(4096, 512, 1) == 512
     # awkward sizes fall back to any divisor
     assert 24 % _pick_q_block(24, 512, 16) == 0
+
+
+def test_attend_value_head_dim_differs_from_query_key():
+    """Latent attention's shapes: queries and keys of 12, values of 8, a
+    score scale of its own; against the naive softmax."""
+    b, s, h, hd, hd_v, scale = 2, 24, 4, 12, 8, 0.3
+    key = jax.random.PRNGKey(5)
+    q = jax.random.normal(key, (b, s, h, hd))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, h, hd))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, h, hd_v))
+    pos = jnp.broadcast_to(jnp.arange(s), (b, s))
+    got = attn.attend(q, k, v, pos, pos, q_block=8, kv_block=8, scale=scale)
+    assert got.shape == (b, s, h, hd_v)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+    probs = jax.nn.softmax(jnp.where(causal, scores, -1e30), axis=-1)
+    want = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+def test_attend_equal_dims_path_is_unchanged():
+    """The grouped-query path (values as wide as keys, the default
+    ``1/sqrt(hd)`` scale) traces to the same program as before the value
+    size and scale became options: the same jaxpr as an explicit scale."""
+    b, s, h, kvh, hd = 2, 16, 4, 2, 8
+    q = jnp.zeros((b, s, h, hd))
+    k = v = jnp.zeros((b, s, kvh, hd))
+    pos = jnp.broadcast_to(jnp.arange(s), (b, s))
+    default = jax.make_jaxpr(lambda q, k, v: attn.attend(q, k, v, pos, pos))(q, k, v)
+    explicit = jax.make_jaxpr(lambda q, k, v: attn.attend(q, k, v, pos, pos,
+                                                         scale=1.0 / np.sqrt(hd)))(q, k, v)
+    assert str(default) == str(explicit)
+    assert attn.attend(q, k, v, pos, pos).shape == (b, s, h, hd)
+
+
+@pytest.mark.parametrize("window", [None, 4])
+def test_decode_steps_write_the_cache_after_the_layers(window):
+    """Decode steps whose layers read the cache and attend their own token
+    beside it, the tokens written after the last layer: over several steps,
+    past a sliding window's ring wrap, each step's logits equal a prefill of
+    the whole sequence so far."""
+    from repro.models import transformer as tr
+    from repro.models.common import IDENTITY_MAT
+
+    cfg = tr.TransformerConfig(n_layers=2, d_model=32, n_heads=4, n_kv_heads=2, d_ff=64,
+                               vocab=64, window=window)
+    params = tr.init(jax.random.PRNGKey(4), cfg)
+    b, p, steps = 2, 6, 5
+    toks = jax.random.randint(jax.random.PRNGKey(5), (b, p + steps), 0, cfg.vocab)
+    prefill = jax.jit(lambda t, c: tr.prefill(cfg, params, dict(tokens=t), IDENTITY_MAT, c))
+    decode = jax.jit(lambda c, t: tr.decode_step(cfg, params, c, t, IDENTITY_MAT))
+    cache, _ = prefill(toks[:, :p], tr.init_decode_state(cfg, b, p + steps, jnp.float32))
+    for j in range(steps):
+        cache, got = decode(cache, toks[:, p + j:p + j + 1])
+        _, want = prefill(toks[:, :p + j + 1],
+                          tr.init_decode_state(cfg, b, p + steps, jnp.float32))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=5e-4, atol=5e-4)
+    assert int(cache.length) == p + steps

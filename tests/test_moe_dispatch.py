@@ -90,3 +90,27 @@ def test_expert_weights_shapes_with_partitions():
     blk = moe._block_init(jax.random.PRNGKey(0), cfg2)
     assert blk["w1"].shape == (8, 32, 32)  # [E*parts, D, F/parts]
     assert blk["w2"].shape == (8, 32, 32)
+
+
+def test_tied_embeddings_serve_through_the_embedding_transpose():
+    """With ``tie_embeddings`` the serving head is the embedding's transpose
+    (no ``lm_head`` leaf): prefill's and decode's logits equal the forward
+    pass's.  A capacity factor that drops no token keeps the two paths'
+    routing alike."""
+    from repro.models.common import IDENTITY_MAT
+
+    cfg = moe.MoEConfig(n_layers=2, d_model=32, n_heads=4, n_kv_heads=2, d_ff=64, vocab=64,
+                        n_experts=4, top_k=2, tie_embeddings=True, capacity_factor=8.0)
+    params = moe.init(jax.random.PRNGKey(2), cfg)
+    assert "lm_head" not in params
+    b, p = 2, 6
+    toks = jax.random.randint(jax.random.PRNGKey(3), (b, p + 1), 0, cfg.vocab)
+    hidden, _ = moe.forward(cfg, params, {"tokens": toks}, IDENTITY_MAT)
+    full = hidden @ params["embed"].T
+    cache = moe.init_decode_state(cfg, b, p + 1, jnp.float32)
+    cache, logits = moe.prefill(cfg, params, {"tokens": toks[:, :p]}, IDENTITY_MAT, cache)
+    np.testing.assert_allclose(np.asarray(logits[:, 0]), np.asarray(full[:, p - 1]),
+                               rtol=1e-4, atol=1e-4)
+    _, logits = moe.decode_step(cfg, params, cache, toks[:, p:], IDENTITY_MAT)
+    np.testing.assert_allclose(np.asarray(logits[:, 0]), np.asarray(full[:, p]),
+                               rtol=1e-4, atol=1e-4)
